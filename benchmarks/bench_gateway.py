@@ -60,11 +60,13 @@ SPLIT_MEM_FRAC = 2e-4      # LeNet overflows one UAV -> forced chain split
 
 
 def make_rollout(uavs: int, window: int, per_frame: int,
-                 cache: PlanFnCache) -> FleetRollout:
+                 cache: PlanFnCache,
+                 use_kernels: bool = False) -> FleetRollout:
     devs = make_devices(uavs, mem_frac=SPLIT_MEM_FRAC)
     spec = RolloutSpec(frames=window, requests_per_frame=per_frame,
                        recovery_prob=0.5)
-    return FleetRollout(CH, devs, MC, spec, plan_cache=cache, seed=0)
+    return FleetRollout(CH, devs, MC, spec, plan_cache=cache, seed=0,
+                        use_kernels=use_kernels)
 
 
 def make_gateway(rollout: FleetRollout, base: np.ndarray, window: int,

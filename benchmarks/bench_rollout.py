@@ -260,14 +260,15 @@ def bench_kernel_path(batch: int, frames: int, uavs: int, steps: int,
             "bitwise_equal": bitwise}
 
 
-def bench_parity(frames: int, uavs: int) -> Dict:
+def bench_parity(frames: int, uavs: int, use_kernels: bool = False) -> Dict:
     """B = 1, frozen dynamics: per-frame parity vs the legacy oracle."""
     mc = cnn_cost(LENET)
     devs = make_devices(uavs)
     pos = hex_init(uavs, 40.0, jitter=0.5, seed=1)
     rng = np.random.default_rng(7)
     sources = rng.integers(0, uavs, size=(frames, 1))
-    ro = FleetRollout(CH, devs, mc, RolloutSpec(frames=frames), seed=0)
+    ro = FleetRollout(CH, devs, mc, RolloutSpec(frames=frames), seed=0,
+                      use_kernels=use_kernels)
     trace = ro.run(pos, n_trajectories=1, sources=sources)
     oracle = LLHRPlanner(CH, placement_solver=solve_chain_dp,
                          optimize_positions=False)

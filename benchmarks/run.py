@@ -22,8 +22,9 @@ Modes:
                      feasibility, parity and zero-retrace asserts stay on,
                      speedup asserts are skipped.
 
-The dry-run/roofline benchmark (reports/dryrun) is driven separately by
-scripts/run_dryrun_all.sh since it needs a 512-device process.
+Compiled programs persist across runs in JAX's compilation cache
+(``repro.runtime.compile_cache``: ``$JAX_COMPILATION_CACHE_DIR`` or the
+checkout's ``.jax_cache/``).
 """
 from __future__ import annotations
 
@@ -87,6 +88,8 @@ def main(argv=None) -> None:
     ap.add_argument("--out", type=str, default="benchmarks",
                     help="directory for BENCH_*.json artifacts")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         # CI runs under the trace-discipline sanitizer: NaN debugging on,
         # and any plan-cache retrace inside the pipeline fails the run

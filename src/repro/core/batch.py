@@ -51,29 +51,45 @@ from repro.core.channel import RadioParams
 
 def pairwise_dist_batched(positions: jnp.ndarray) -> jnp.ndarray:
     """[..., U, 2] positions -> [..., U, U] Euclidean distances."""
-    diff = positions[..., :, None, :] - positions[..., None, :, :]
-    return jnp.sqrt((diff ** 2).sum(-1))
+    x, y = positions[..., 0], positions[..., 1]
+    dx = x[..., :, None] - x[..., None, :]
+    dy = y[..., :, None] - y[..., None, :]
+    return jnp.sqrt(dx ** 2 + dy ** 2)
 
 
-def link_gain_batched(dist: jnp.ndarray, params: RadioParams,
-                      gain_scale: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """eq. (4) with the same d0 = 1 m clamp as ``RadioChannel.gain``."""
-    d = jnp.maximum(dist, 1.0)
-    g = params.h0 / d ** 2
-    if gain_scale is not None:
-        g = g * gain_scale
-    return g
+# The channel formulas below fold every constant into ONE Python float per
+# expression and divide only by arrays: XLA on TPU rewrites a divide by a
+# constant into a multiply by its reciprocal (and A / (B / C) into
+# A * C / B) while Mosaic divides, so any other spelling rounds differently
+# in the jnp planner and in the link-geometry kernel, which computes the
+# same expressions (``link_constants``).
+
+
+def link_constants(params: RadioParams, bits: Optional[float] = None
+                   ) -> Tuple[float, float, float]:
+    """(threshold, snr, rate) coefficients of eq. (7) and eq. (5) with the
+    eq. (4) gain h0 / d^2 folded in:
+
+        threshold = d^2 / gain_scale * noise (2^(bits / (B tau)) - 1) / h0
+        rate      = ln(1 + p gain_scale h0 / (noise d^2)) * B / ln 2
+    """
+    bits = params.packet_bits if bits is None else bits
+    spectral = bits * math.log(2.0) / (params.bandwidth_hz * params.tau)
+    return (params.noise_watts * (math.exp(spectral) - 1.0) / params.h0,
+            params.h0 / params.noise_watts,
+            params.bandwidth_hz / math.log(2.0))
 
 
 def power_threshold_batched(dist: jnp.ndarray, params: RadioParams,
                             bits: Optional[float] = None,
                             gain_scale: Optional[jnp.ndarray] = None
                             ) -> jnp.ndarray:
-    """eq. (7): minimum power delivering ``bits`` within tau, per link."""
-    bits = params.packet_bits if bits is None else bits
-    spectral = bits * math.log(2.0) / (params.bandwidth_hz * params.tau)
-    gain = link_gain_batched(dist, params, gain_scale)
-    return params.noise_watts / gain * (math.exp(spectral) - 1.0)
+    """eq. (7): minimum power delivering ``bits`` within tau, per link, at
+    the eq. (4) gain with the same d0 = 1 m clamp as ``RadioChannel.gain``."""
+    d2 = jnp.maximum(dist, 1.0) ** 2
+    if gain_scale is not None:
+        d2 = d2 / gain_scale
+    return d2 * link_constants(params, bits)[0]
 
 
 @dataclass(frozen=True)
@@ -131,8 +147,12 @@ def rate_matrix_batched(dist: jnp.ndarray, power: jnp.ndarray,
     """eq. (5) at the solved powers: rho_{i,k} [B,U,U]; 0 on infeasible
     links, inf on the diagonal (self-transfer is free)."""
     U = dist.shape[-1]
-    p_rx = link_gain_batched(dist, params, gain_scale) * power[..., :, None]
-    rate = params.bandwidth_hz * jnp.log2(1.0 + p_rx / params.noise_watts)
+    _, snr_c, rate_c = link_constants(params)
+    snr = power[..., :, None] * snr_c
+    if gain_scale is not None:
+        snr = snr * gain_scale
+    snr = snr / jnp.maximum(dist, 1.0) ** 2
+    rate = jnp.log(1.0 + snr) * rate_c
     rate = jnp.where(link_feasible, rate, 0.0)
     return jnp.where(jnp.eye(U, dtype=bool), jnp.inf, rate)
 
@@ -828,7 +848,7 @@ def _reconstruct_assignments(latency: np.ndarray, s_best: np.ndarray,
 
 __all__ = [
     "BatchPowerSolution", "BatchPositionSolution", "pairwise_dist_batched",
-    "link_gain_batched", "power_threshold_batched", "solve_power_batched",
+    "link_constants", "power_threshold_batched", "solve_power_batched",
     "rate_matrix_batched", "solve_chain_dp_batched",
     "solve_chain_dp_batched_unrolled", "solve_chain_dp_multisource",
     "solve_positions_batched", "links_from_assignment_batched",

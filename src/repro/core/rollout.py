@@ -515,16 +515,17 @@ def make_rollout_fn(on_trace, *, params: RadioParams, compute, memory,
     # trace exactly like the unsharded path, so retrace accounting is
     # mesh-transparent.
     from jax.sharding import PartitionSpec as P
-    from repro.parallel.sharding import shard_map_compat
     axis = mesh.axis_names[0]
     b_spec, tb_spec = P(axis), P(None, axis)
     n_chaos = int(with_gain) + int(with_drain)   # trailing [T, B, ...] streams
-    sharded = shard_map_compat(
-        rollout, mesh,
+    # replication checking off: the per-shard scan's outputs are not
+    # inferable as replicated or varying
+    sharded = jax.shard_map(
+        rollout, mesh=mesh,
         in_specs=(b_spec, b_spec, b_spec, b_spec,
                   tb_spec, tb_spec, tb_spec, tb_spec, tb_spec)
         + (tb_spec,) * n_chaos,
-        out_specs=tb_spec)
+        out_specs=tb_spec, check_vma=False)
     return jax.jit(sharded)
 
 

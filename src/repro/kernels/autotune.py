@@ -7,7 +7,9 @@ backend: on CPU the kernels run in Pallas interpret mode, where every
 grid cell is executed sequentially inside the traced program — so the
 fastest configuration is ONE cell covering the whole operand (the body
 then vectorizes exactly like the jnp oracle); on TPU the tiles must fit
-VMEM and align to the 8x128 register file, so small per-cell blocks win.
+VMEM and obey Mosaic's tiling rule: the last two dimensions of every
+block are multiples of (8, 128) or equal to the whole array dimension
+(``divisor_leq``'s ``align`` snaps compiled blocks to it).
 
 ``lookup(kernel, ...)`` resolves a block dict for a (kernel, backend,
 shape, dtype) query: an exact shape-keyed entry wins, then the backend
@@ -22,28 +24,25 @@ from typing import Dict, Optional
 from repro.kernels import default_backend
 
 #: (kernel, backend[, U, L, S, dtype]) -> block dict.  0 = whole axis.
-#: Backend-level rows are the measured defaults; shape-keyed rows are
-#: overrides for specific production shapes (add rows as they are
-#: profiled — the committed BENCH_kernels.json records the shapes that
-#: matter).
+#: No row has been tuned by a measurement yet: the CPU rows follow from
+#: interpret mode's sequential grid, and the TPU rows are the smallest
+#: tiles that the v5e compiler accepts and that fit the default scoped
+#: VMEM at the benchmark shapes (``tests/test_tpu_compile.py`` compiles
+#: them).  Shape-keyed rows override a backend row for one shape.
 TABLE: Dict[tuple, Dict[str, int]] = {
     # CPU = interpret mode: one grid cell, fully vectorized body.
     ("tropical_dp", "cpu"): {"block_b": 0, "block_m": 0, "block_s": 0},
     ("link_geometry", "cpu"): {"block_b": 0, "block_u": 0},
-    # TPU: per-row DP tiles (the [L, S+1] working set stays in VMEM),
-    # lane-width state tiles; link geometry tiles rows of the [U, U]
-    # matrices at the 8-sublane granularity.
-    ("tropical_dp", "tpu"): {"block_b": 1, "block_m": 1, "block_s": 128},
-    ("link_geometry", "tpu"): {"block_b": 8, "block_u": 128},
+    # TPU: up to 8 scenarios per DP cell (fewer where the candidate slab
+    # would overflow VMEM), the whole slot axis up to 8 slots (tr0 and
+    # the outputs put M on sublanes) and the whole state axis (S sits on
+    # lanes: whole or a multiple of 128); link geometry tiles 8 scenarios
+    # (B sits on sublanes of the [B, U] planes) by whole rows.
+    ("tropical_dp", "tpu"): {"block_b": 8, "block_m": 8, "block_s": 0},
+    ("link_geometry", "tpu"): {"block_b": 8, "block_u": 0},
     # GPU (Triton) runs interpret today as well — same shape as CPU.
     ("tropical_dp", "gpu"): {"block_b": 0, "block_m": 0, "block_s": 0},
     ("link_geometry", "gpu"): {"block_b": 0, "block_u": 0},
-    # Shape-keyed overrides: the paper-scale U = L = S = 32 instance
-    # fits a whole scenario per TPU cell.
-    ("tropical_dp", "tpu", 32, 32, 32, "float32"):
-        {"block_b": 1, "block_m": 1, "block_s": 32},
-    ("link_geometry", "tpu", 32, None, None, "float32"):
-        {"block_b": 8, "block_u": 32},
     # Backend-independent defaults for the CNN-layer kernels: these tile
     # a grid whose cells are identical on every backend (interpret mode
     # snaps blocks to whole axes via divisor_leq anyway), so one
@@ -59,17 +58,22 @@ TABLE: Dict[tuple, Dict[str, int]] = {
 }
 
 
-def divisor_leq(n: int, target: int) -> int:
-    """Largest divisor of ``n`` that is <= ``target`` (>= 1).
+def divisor_leq(n: int, target: int, align: int = 1) -> int:
+    """Largest divisor of ``n`` that is <= ``target`` and a multiple of
+    ``align``; ``n`` itself when no such divisor exists.
 
     Pallas block shapes must tile their axis exactly (a ragged trailing
     block would read padding into the reductions), so requested block
-    sizes are snapped down to a divisor of the axis length.
+    sizes are snapped down to a divisor of the axis length.  Compiled TPU
+    kernels pass ``align`` = 8 (second-to-last block dimension) or 128
+    (last): Mosaic accepts such a block only at that granularity or as
+    the whole axis.
     """
-    target = max(1, min(int(target), int(n)))
-    while n % target:
-        target -= 1
-    return target
+    n = int(n)
+    for d in range(max(1, min(int(target), n)), 0, -1):
+        if n % d == 0 and d % align == 0:
+            return d
+    return n
 
 
 def lookup(kernel: str, *, U: Optional[int] = None, L: Optional[int] = None,

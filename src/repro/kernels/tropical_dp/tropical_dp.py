@@ -41,6 +41,10 @@ from jax.experimental import pallas as pl
 from repro.kernels import resolve_interpret
 from repro.kernels.autotune import divisor_leq, lookup
 
+#: Largest candidate slab a compiled cell may hold: 4 MiB compiles on
+#: v5e (U = L = 32, 8 slots), 8 MiB exhausts its default scoped VMEM.
+_VMEM_SLAB_BYTES = 4 << 20
+
 
 def _dp_step_kernel(dp_ref, tr_ref, tr0_ref, ct_ref, ok_ref,
                     row_ref, pa_ref, ps_ref, *, n_layers: int,
@@ -101,6 +105,10 @@ def tropical_dp_step(dp: jnp.ndarray, tr: jnp.ndarray, tr0: jnp.ndarray,
     inf) and the first-argmin parent pointers.  Block sizes default to
     the autotune table (``kernels.autotune``); 0/None = whole axis, and
     requests are snapped down to divisors so tiles are never ragged.
+    Compiled (non-interpret) launches also snap to Mosaic's tiling: the
+    slot block sits on sublanes of the ``tr0``/output blocks (a multiple
+    of 8 or all of M) and the state block on lanes (a multiple of 128 or
+    all of S).
     """
     interpret = resolve_interpret(interpret)
     B, M, L, Sp1 = dp.shape
@@ -109,9 +117,16 @@ def tropical_dp_step(dp: jnp.ndarray, tr: jnp.ndarray, tr0: jnp.ndarray,
     block_b = tuned.get("block_b", 0) if block_b is None else block_b
     block_m = tuned.get("block_m", 0) if block_m is None else block_m
     block_s = tuned.get("block_s", 0) if block_s is None else block_s
+    sublane, lane = (1, 1) if interpret else (8, 128)
     bb = divisor_leq(B, block_b or B)
-    bm = divisor_leq(M, block_m or M)
-    bs = divisor_leq(S, block_s or S)
+    bm = divisor_leq(M, block_m or M, sublane)
+    bs = divisor_leq(S, block_s or S, lane)
+    if not interpret:
+        # one cell's [bb, bm, L, bs, S+1] candidate slab, S+1 padded to
+        # whole lanes, must leave the scoped VMEM room for its temporaries
+        slab = bm * L * bs * (-(-Sp1 // lane) * lane) * dp.dtype.itemsize
+        while bb > 1 and bb * slab > _VMEM_SLAB_BYTES:
+            bb = divisor_leq(B, bb - 1)
     grid = (B // bb, M // bm, S // bs)
     kernel = functools.partial(_dp_step_kernel, n_layers=L, n_states=S)
     return pl.pallas_call(

@@ -26,12 +26,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 Pytree = Any
 
 
-# the version shim lives in repro.parallel.sharding now (the fleet rollout
-# shards its trajectory axis through the same entry point); this alias keeps
-# the pipeline module's historical name working
-from repro.parallel.sharding import shard_map_compat as _shard_map
-
-
 def stage_params(params_per_block: Sequence[Pytree],
                  boundaries: Sequence[int]) -> List[Pytree]:
     """Group per-block params into per-stage lists per a StagePlan."""
@@ -120,9 +114,11 @@ def pipelined_forward(block_fn: Callable[[Pytree, jnp.ndarray], jnp.ndarray],
             axis)
         return outs
 
-    fn = _shard_map(
-        stage_fn, mesh,
+    # replication checking off: the last-stage psum install is not
+    # inferable
+    fn = jax.shard_map(
+        stage_fn, mesh=mesh,
         in_specs=(P(axis), P(axis), P()),
-        out_specs=P())
+        out_specs=P(), check_vma=False)
     outs = fn(stacked, depths, micro)
     return outs.reshape(b, *x.shape[1:])

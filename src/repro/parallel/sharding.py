@@ -28,20 +28,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 _state = threading.local()
 
 
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions: ``jax.shard_map`` (jax >= 0.6,
-    where replication checking is ``check_vma``) with a fallback to
-    ``jax.experimental.shard_map`` (jax 0.4/0.5, where it is ``check_rep``).
-    Replication checking is disabled either way — the callers' collective
-    patterns (last-stage psum install, per-shard scan) are not inferable."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as exp_shard_map
-    return exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-
-
 # ---------------------------------------------------------------------------
 # 1-D fleet meshes (the rollout's trajectory axis) + topology signatures
 # ---------------------------------------------------------------------------

@@ -20,8 +20,10 @@ contract, not an afterthought:
 * **Double-buffered host->device staging** — the arrival tensor of
   window ``k+1`` is assembled (scheduling + ingest) while the device
   solves window ``k`` on a single worker thread.
-* **Bounded retry around the device call** — a timed-out or failed
-  solve retries under exponential backoff up to ``max_attempts``; an
+* **Bounded retry around the device call** — a timed-out or stalled
+  solve (``DeviceStallError``) retries under exponential backoff up to
+  ``max_attempts``; any other exception is a program error (a failed
+  trace, lowering or compile) and propagates out of ``serve``.  An
   exhausted window sheds its requests (``device_failure``), flips the
   gateway into deterministic degraded-mode admission shedding, and —
   when a ``ReplanController`` is attached — falls through to its
@@ -53,6 +55,7 @@ from __future__ import annotations
 import time
 from collections import defaultdict
 from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import TimeoutError as SolveTimeout
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -545,8 +548,10 @@ class StreamingGateway:
     def _collect(self, w: int, fut, scheduled: List[GatewayRequest],
                  arr: np.ndarray) -> None:
         """Wait (bounded) for window ``w``; retry with exponential
-        backoff on timeout/failure; on exhaustion shed the window and
-        degrade; on success record every scheduled request's result."""
+        backoff on a timeout or device stall — the faults the recovery
+        ladder models; on exhaustion shed the window and degrade; on
+        success record every scheduled request's result.  Any other
+        exception from the solve is a program error and propagates."""
         cfg = self.config
         attempt = 1
         backoff = cfg.retry_base_backoff_s
@@ -554,7 +559,7 @@ class StreamingGateway:
             try:
                 trace = fut.result(timeout=cfg.solve_timeout_s)
                 break
-            except Exception:
+            except (DeviceStallError, SolveTimeout):
                 if attempt >= cfg.max_attempts:
                     self.windows_failed += 1
                     self.device_failures += 1

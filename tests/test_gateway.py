@@ -14,7 +14,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 from repro.runtime.chaos import FaultSchedule                  # noqa: E402
 from repro.runtime.gateway import (ArrivalSchedule,            # noqa: E402
-                                   GatewayConfig, LoadGenerator,
+                                   DeviceStallError, GatewayConfig,
+                                   LoadGenerator,
                                    SERVED, SHED_DEGRADED,
                                    SHED_DEVICE_FAILURE, SHED_EXPIRED,
                                    SHED_QUEUE_FULL, SHED_REASONS,
@@ -265,7 +266,7 @@ class TestRetryLadder:
 
     def test_always_failing_solver_stays_bounded(self):
         def boom(w, arr):
-            raise RuntimeError("device on fire")
+            raise DeviceStallError("device on fire")
         gw = make_gateway(solve=boom, max_attempts=2)
         for _ in range(3):
             gw.submit(0, 1000.0)
@@ -273,6 +274,23 @@ class TestRetryLadder:
         assert rep["windows_failed"] == 3
         assert rep["served"] == 0
         assert gw.shed_counts[SHED_DEVICE_FAILURE] >= 1
+
+    def test_program_error_propagates_out_of_serve(self):
+        """A failed trace, lowering or compile is not a device fault: it
+        must surface, not be retried and shed as device failure."""
+        attempts = []
+
+        def broken(w, arr):
+            attempts.append(w)
+            raise ValueError("Mosaic failed to compile TPU kernel")
+        gw = make_gateway(solve=broken, max_attempts=3)
+        gw.submit(0, 1000.0)
+        with pytest.raises(ValueError, match="Mosaic"):
+            gw.serve(None, n_windows=2)
+        gw.close()
+        assert attempts == [0]
+        assert gw.retries == 0 and gw.windows_failed == 0
+        assert SHED_DEVICE_FAILURE not in gw.shed_counts
 
     def test_fall_through_to_replan_controller_ladder(self):
         class HealthyStub:

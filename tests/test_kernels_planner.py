@@ -435,12 +435,19 @@ class TestAutotune:
         assert autotune.divisor_leq(7, 3) == 1     # prime: whole or 1
         assert autotune.divisor_leq(8, 100) == 8   # clamp to the axis
         assert autotune.divisor_leq(8, 0) == 1
+        # TPU tiling: a multiple of the alignment, else the whole axis
+        assert autotune.divisor_leq(32, 8, 8) == 8
+        assert autotune.divisor_leq(2, 8, 8) == 2
+        assert autotune.divisor_leq(100, 8, 8) == 100
+        assert autotune.divisor_leq(256, 64, 128) == 256
 
-    def test_lookup_fallback_chain(self):
+    def test_lookup_fallback_chain(self, monkeypatch):
+        key = ("tropical_dp", "tpu", 32, 32, 32, "float32")
+        monkeypatch.setitem(autotune.TABLE, key,
+                            {"block_b": 2, "block_m": 8, "block_s": 0})
         exact = autotune.lookup("tropical_dp", U=32, L=32, S=32,
                                 dtype="float32", backend="tpu")
-        assert exact == autotune.TABLE[
-            ("tropical_dp", "tpu", 32, 32, 32, "float32")]
+        assert exact == autotune.TABLE[key]
         generic = autotune.lookup("tropical_dp", U=999, L=1, S=999,
                                   dtype="float32", backend="tpu")
         assert generic == autotune.TABLE[("tropical_dp", "tpu")]

@@ -51,7 +51,7 @@ from tools.tracelint.core import (FuncInfo, ModuleInfo, ProjectIndex, call_name,
 
 #: dotted suffixes whose call marks function-valued arguments as traced.
 _TRACING_WRAPPERS = ("jit", "pmap", "vmap", "pallas_call", "shard_map",
-                     "shard_map_compat", "checkpoint", "remat", "grad",
+                     "checkpoint", "remat", "grad",
                      "value_and_grad", "custom_vjp", "custom_jvp")
 #: lax control-flow primitives whose callable args are traced bodies.
 _LAX_BODIES = ("scan", "while_loop", "fori_loop", "cond", "switch", "map",
