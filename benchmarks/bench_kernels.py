@@ -39,8 +39,7 @@ Two modes share this module:
 * ``<kernel>.bitwise_agree``        — all outputs bit-identical to the
                                       jitted oracle (asserted).
 * ``<kernel>.arithmetic_intensity_flop_per_byte`` — analytic AI at the
-                                      benchmarked shape (see
-                                      ``scripts/make_roofline_table.py``).
+                                      benchmarked shape.
 """
 from __future__ import annotations
 

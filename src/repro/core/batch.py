@@ -465,7 +465,8 @@ def _chain_dp_solve_kernelized(compute: jnp.ndarray, memory: jnp.ndarray,
         return (nb, ns), dev
 
     init = (jnp.full((R,), L, jnp.int32), s_best)
-    _, devs = jax.lax.scan(backward, init, jnp.arange(L - 1, -1, -1))
+    with jax.named_scope("backtrack"):
+        _, devs = jax.lax.scan(backward, init, jnp.arange(L - 1, -1, -1))
     assign = devs[::-1].T.astype(jnp.int32)                         # [R, L]
     assign = jnp.where(jnp.isfinite(latency)[:, None], assign, -1)
     return assign.reshape(B, M, L), latency.reshape(B, M)
@@ -590,7 +591,10 @@ def _chain_dp_solve(compute: jnp.ndarray, memory: jnp.ndarray,
         return (nb, ns), dev
 
     init = (jnp.full((B,), L, jnp.int32), s_best)
-    _, devs = jax.lax.scan(backward, init, jnp.arange(L - 1, -1, -1))
+    # the scope holds the reverse scan alone: opened above ``init`` it
+    # renumbers instructions of the compiled rollout
+    with jax.named_scope("backtrack"):
+        _, devs = jax.lax.scan(backward, init, jnp.arange(L - 1, -1, -1))
     assign = devs[::-1].T.astype(jnp.int32)                         # [B, L]
     assign = jnp.where(jnp.isfinite(latency)[:, None], assign, -1)
     return assign, latency

@@ -205,6 +205,13 @@ def make_plan_fn(*, params: RadioParams, compute, memory, act_bits,
     wavefront (all source slots in one launch per step).  The emitted
     plans are bitwise-identical to the jnp path; the flag only selects
     the program, so it must be part of any compiled-plan cache key.
+
+    Each stage runs under a ``jax.named_scope`` — ``p2``, ``geometry``,
+    ``dp`` (its reverse scan under ``backtrack``) and ``tighten`` — and
+    ``make_rollout_fn``'s frame adds ``dynamics`` and ``energy``.  Scopes
+    are op metadata only: they name the stages' device ops in a profiler
+    trace and leave the compiled program and its instruction names as
+    they were.
     """
     compute = jnp.asarray(compute, jnp.float32)
     memory = jnp.asarray(memory, jnp.float32)
@@ -217,35 +224,43 @@ def make_plan_fn(*, params: RadioParams, compute, memory, act_bits,
 
     def geometry(positions, active, gain_scale, p2_links):
         if p2 is not None:
-            positions, _, _, _ = _positions_pgd(
-                positions, p2_links,
-                jnp.float32(position_coeff(params)), jnp.float32(p2.lr),
-                jnp.float32(2.0 * p2.radius),
-                jnp.float32(coverage_radius(U, p2.radius)),
-                positions.mean(axis=1), p2.steps, p2.repair_iters)
-        if use_kernels:
-            from repro.kernels.link_geometry.ops import fused_link_geometry
-            dist, th, rate = fused_link_geometry(
-                positions, params, active=active, gain_scale=gain_scale)
+            with jax.named_scope("p2"):
+                positions, _, _, _ = _positions_pgd(
+                    positions, p2_links,
+                    jnp.float32(position_coeff(params)), jnp.float32(p2.lr),
+                    jnp.float32(2.0 * p2.radius),
+                    jnp.float32(coverage_radius(U, p2.radius)),
+                    positions.mean(axis=1), p2.steps, p2.repair_iters)
+        with jax.named_scope("geometry"):
+            if use_kernels:
+                from repro.kernels.link_geometry.ops import \
+                    fused_link_geometry
+                dist, th, rate = fused_link_geometry(
+                    positions, params, active=active, gain_scale=gain_scale)
+                return positions, dist, th, rate
+            dist = pairwise_dist_batched(positions)
+            th = power_threshold_batched(dist, params, gain_scale=gain_scale)
+            pw = solve_power_batched(dist, params, active=active,
+                                     gain_scale=gain_scale,
+                                     threshold_matrix=th)
+            rate = rate_matrix_batched(dist, pw.power, params,
+                                       pw.link_feasible,
+                                       gain_scale=gain_scale)
             return positions, dist, th, rate
-        dist = pairwise_dist_batched(positions)
-        th = power_threshold_batched(dist, params, gain_scale=gain_scale)
-        pw = solve_power_batched(dist, params, active=active,
-                                 gain_scale=gain_scale, threshold_matrix=th)
-        rate = rate_matrix_batched(dist, pw.power, params, pw.link_feasible,
-                                   gain_scale=gain_scale)
-        return positions, dist, th, rate
 
     def solve(positions, source, active, gain_scale, p2_links):
         positions, dist, th, rate = geometry(positions, active, gain_scale,
                                              p2_links)
-        assign, latency = _chain_dp_solve(
-            compute, memory, act_bits, input_bits, mem_cap, compute_cap,
-            throughput, rate, source, active, order,
-            use_kernel=use_kernels)
-        used = links_from_assignment_batched(assign, source, U)
-        power = solve_power_batched(dist, params, links=used, active=active,
-                                    threshold_matrix=th).power
+        with jax.named_scope("dp"):
+            assign, latency = _chain_dp_solve(
+                compute, memory, act_bits, input_bits, mem_cap, compute_cap,
+                throughput, rate, source, active, order,
+                use_kernel=use_kernels)
+        with jax.named_scope("tighten"):
+            used = links_from_assignment_batched(assign, source, U)
+            power = solve_power_batched(dist, params, links=used,
+                                        active=active,
+                                        threshold_matrix=th).power
         return positions, power, rate, assign, latency
 
     S = U if max_sources is None else max(1, min(U, int(max_sources)))
@@ -255,40 +270,43 @@ def make_plan_fn(*, params: RadioParams, compute, memory, act_bits,
         positions, dist, th, rate = geometry(positions, active, gain_scale,
                                              p2_links)
         B = positions.shape[0]
-        n_req = jnp.asarray(n_req, jnp.float32)
-        if S < U:
-            # a frame with RQ total arrivals has at most RQ distinct
-            # sources: gather the S largest counts, solve only those slots
-            slot_src = jnp.argsort(-n_req, axis=-1)[:, :S] \
-                .astype(jnp.int32)                          # [B, S]
-        else:
-            slot_src = jnp.broadcast_to(
-                jnp.arange(U, dtype=jnp.int32), (B, U))
-        slot_cnt = jnp.take_along_axis(n_req, slot_src, -1)  # [B, S]
-        assign_s, lat_s = _chain_dp_solve_multi(
-            compute, memory, act_bits, input_bits, mem_cap, compute_cap,
-            throughput, rate, slot_src, active, order,
-            use_kernel=use_kernels)                         # [B,S,L],[B,S]
-        requested = slot_cnt > 0
-        served = requested & jnp.isfinite(lat_s)
-        # arrival-weighted per-request latency; a requested source the DP
-        # could not place makes the whole frame infeasible (inf), exactly
-        # like an INFEASIBLE placement in the legacy request loop
-        weighted = jnp.where(requested, slot_cnt * lat_s, 0.0).sum(-1)
-        latency = weighted / jnp.maximum(n_req.sum(-1), 1.0)
-        # exact shared-cap pricing: the aggregate per-UAV MACs of the whole
-        # stream against the eq. (11b) period budget
-        load = placement_compute_load(
-            assign_s, jnp.where(requested, slot_cnt, 0.0), compute, U)
-        cap_ok = shared_cap_feasible(load, compute_cap)
-        latency = jnp.where(cap_ok, latency, jnp.inf)
-        # tighten P1 to the union of the links every SERVED source uses
-        used = jax.vmap(
-            lambda a, s: links_from_assignment_batched(a, s, U),
-            in_axes=1, out_axes=1)(assign_s, slot_src)      # [B,S,U,U]
-        used = (used & served[:, :, None, None]).any(1)
-        power = solve_power_batched(dist, params, links=used, active=active,
-                                    threshold_matrix=th).power
+        with jax.named_scope("dp"):
+            n_req = jnp.asarray(n_req, jnp.float32)
+            if S < U:
+                # a frame with RQ total arrivals has at most RQ distinct
+                # sources: gather the S largest counts, solve only those slots
+                slot_src = jnp.argsort(-n_req, axis=-1)[:, :S] \
+                    .astype(jnp.int32)                          # [B, S]
+            else:
+                slot_src = jnp.broadcast_to(
+                    jnp.arange(U, dtype=jnp.int32), (B, U))
+            slot_cnt = jnp.take_along_axis(n_req, slot_src, -1)  # [B, S]
+            assign_s, lat_s = _chain_dp_solve_multi(
+                compute, memory, act_bits, input_bits, mem_cap, compute_cap,
+                throughput, rate, slot_src, active, order,
+                use_kernel=use_kernels)                         # [B,S,L],[B,S]
+            requested = slot_cnt > 0
+            served = requested & jnp.isfinite(lat_s)
+            # arrival-weighted per-request latency; a requested source the DP
+            # could not place makes the whole frame infeasible (inf), exactly
+            # like an INFEASIBLE placement in the legacy request loop
+            weighted = jnp.where(requested, slot_cnt * lat_s, 0.0).sum(-1)
+            latency = weighted / jnp.maximum(n_req.sum(-1), 1.0)
+            # exact shared-cap pricing: the aggregate per-UAV MACs of the whole
+            # stream against the eq. (11b) period budget
+            load = placement_compute_load(
+                assign_s, jnp.where(requested, slot_cnt, 0.0), compute, U)
+            cap_ok = shared_cap_feasible(load, compute_cap)
+            latency = jnp.where(cap_ok, latency, jnp.inf)
+        with jax.named_scope("tighten"):
+            # tighten P1 to the union of the links every SERVED source uses
+            used = jax.vmap(
+                lambda a, s: links_from_assignment_batched(a, s, U),
+                in_axes=1, out_axes=1)(assign_s, slot_src)      # [B,S,U,U]
+            used = (used & served[:, :, None, None]).any(1)
+            power = solve_power_batched(dist, params, links=used,
+                                        active=active,
+                                        threshold_matrix=th).power
         if S < U:
             # scatter the solved slots back onto the U source axis;
             # unrequested sources report assign -1 / latency inf
@@ -352,8 +370,10 @@ def _frame_tx_time_multi(assign, n_req, rate, act_bits, input_bits):
                               act_bits, input_bits)
         return tx
 
-    tx_s = jax.vmap(one, in_axes=1, out_axes=1)(assign, sources)  # [B,S,U]
-    return (tx_s * n_req[:, :, None]).sum(1)
+    with jax.named_scope("energy"):
+        # [B, S, U]
+        tx_s = jax.vmap(one, in_axes=1, out_axes=1)(assign, sources)
+        return (tx_s * n_req[:, :, None]).sum(1)
 
 
 # ---------------------------------------------------------------------------
@@ -453,31 +473,32 @@ def make_rollout_fn(on_trace, *, params: RadioParams, compute, memory,
             extra = xs[5:]
             gain_t = extra[0] if with_gain else None
             drain_t = extra[-1] if with_drain else None
-            # 1. mobility: bounded step toward the waypoint, plus jitter
-            to_wp = waypoint - pos
-            nrm = jnp.linalg.norm(to_wp, axis=-1, keepdims=True)
-            pos = pos + to_wp * jnp.minimum(1.0, drift / jnp.maximum(
-                nrm, 1e-9)) + jit_t
-            # 2. Bernoulli failure / recovery, then forced injections.
-            # Recovery applies to UAVs that entered the frame dead — a UAV
-            # failing THIS frame stays down at least one frame, so the
-            # observed per-frame failure rate is the documented
-            # failure_prob, not failure_prob * (1 - recovery_prob).
-            revived = ~alive & (rec_t < p_recover)
-            alive = (alive & (fail_t >= p_fail)) | revived
-            alive = alive & ~dead_t
-            # 3. battery gate: drained at the frame boundary => excluded
-            powered = charge > 0.0
-            active = alive & powered
-            # 4. arrivals drawn on a dead UAV are captured by the FIRST
-            # survivor (the legacy delegation maps a dead source to the
-            # lowest-indexed one).  An all-dead fleet keeps the orphaned
-            # counts on (inactive) UAV 0, so the frame prices as infeasible
-            # instead of silently serving nobody.
-            first_active = jnp.argmax(active, axis=-1).astype(jnp.int32)
-            n_live = jnp.where(active, arr_t, 0.0)
-            orphaned = (arr_t - n_live).sum(-1)
-            n_eff = n_live.at[rows, first_active].add(orphaned)
+            with jax.named_scope("dynamics"):
+                # 1. mobility: bounded step toward the waypoint, plus jitter
+                to_wp = waypoint - pos
+                nrm = jnp.linalg.norm(to_wp, axis=-1, keepdims=True)
+                pos = pos + to_wp * jnp.minimum(1.0, drift / jnp.maximum(
+                    nrm, 1e-9)) + jit_t
+                # 2. Bernoulli failure / recovery, then forced injections.
+                # Recovery applies to UAVs that entered the frame dead — a
+                # UAV failing THIS frame stays down at least one frame, so
+                # the observed per-frame failure rate is the documented
+                # failure_prob, not failure_prob * (1 - recovery_prob).
+                revived = ~alive & (rec_t < p_recover)
+                alive = (alive & (fail_t >= p_fail)) | revived
+                alive = alive & ~dead_t
+                # 3. battery gate: drained at the frame boundary => excluded
+                powered = charge > 0.0
+                active = alive & powered
+                # 4. arrivals drawn on a dead UAV are captured by the FIRST
+                # survivor (the legacy delegation maps a dead source to the
+                # lowest-indexed one).  An all-dead fleet keeps the orphaned
+                # counts on (inactive) UAV 0, so the frame prices as infeasible
+                # instead of silently serving nobody.
+                first_active = jnp.argmax(active, axis=-1).astype(jnp.int32)
+                n_live = jnp.where(active, arr_t, 0.0)
+                orphaned = (arr_t - n_live).sum(-1)
+                n_eff = n_live.at[rows, first_active].add(orphaned)
             # 5. the fused multi-source planning tick, in-trace
             p2_links = None if links_const is None else \
                 jnp.broadcast_to(links_const, (B, U, U))
@@ -485,18 +506,21 @@ def make_rollout_fn(on_trace, *, params: RadioParams, compute, memory,
              cap_ok) = solve(pos, n_eff, active, gain_t, p2_links)
             # 6. energy accounting + battery carry.  ``load`` is already
             # the arrival-weighted aggregate MACs; an infeasible frame is
-            # not served, so it spends nothing beyond hover.
-            feasible = jnp.isfinite(latency)
+            # not served, so it spends nothing beyond hover.  The airtime
+            # opens its own ``energy`` scope: one opened around this call
+            # would renumber instructions of the compiled program.
             tx_time = _frame_tx_time_multi(assign, n_eff, rate, act_j,
                                            input_j)
-            e_cmp = jnp.where(feasible[:, None], kappa * load, 0.0)
-            e_tx = jnp.where(feasible[:, None], power * tx_time, 0.0)
-            drain = jnp.where(active, e_cmp + e_tx + hover_e, 0.0)
-            if with_drain:
-                # scripted battery drops (chaos): charged whether or not
-                # the UAV served this frame — a physical energy loss
-                drain = drain + drain_t
-            charge = jnp.maximum(charge - drain, 0.0)
+            with jax.named_scope("energy"):
+                feasible = jnp.isfinite(latency)
+                e_cmp = jnp.where(feasible[:, None], kappa * load, 0.0)
+                e_tx = jnp.where(feasible[:, None], power * tx_time, 0.0)
+                drain = jnp.where(active, e_cmp + e_tx + hover_e, 0.0)
+                if with_drain:
+                    # scripted battery drops (chaos): charged whether or not
+                    # the UAV served this frame — a physical energy loss
+                    drain = drain + drain_t
+                charge = jnp.maximum(charge - drain, 0.0)
             out = (pos, active, charge, latency,
                    jnp.where(feasible, power.sum(-1), 0.0), feasible,
                    cap_ok, assign, lat_src, n_eff, e_tx, e_cmp)

@@ -23,6 +23,7 @@ from functools import partial
 from typing import List, Optional, Sequence, Tuple, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.rollout import (RolloutSpec, make_rollout_fn,
                                 percentile_with_inf)
@@ -290,15 +291,60 @@ class FleetRollout(ScenarioEngine):
         windows independently of call order — the streaming gateway
         derives one child generator per serving window — pass it so a
         retried or reordered call consumes bit-identical draws.
+
+        Under the JAX profiler the call shows as five host spans, in
+        order: ``rollout.draws`` (host draws and input validation),
+        ``rollout.put`` (host-to-device placement), ``rollout.scan`` (the
+        device call, to its end), ``rollout.fetch`` (the copy back) and
+        ``rollout.widen`` (to [B, T, ...] float64/int64).
         """
         import jax
-        import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
 
-        U = len(self.devices)
         B = n_trajectories
         T = self.spec.frames if frames is None else frames
-        rng = self._rng if rng is None else rng
+        with TraceAnnotation("rollout.draws"):
+            inputs, bdims = self._host_inputs(
+                base_positions, B, T, self._rng if rng is None else rng,
+                charge0, alive0, forced_failures, sources, arrivals,
+                waypoints, forced, gain_scale, extra_drain)
+        with TraceAnnotation("rollout.put"):
+            if mesh is not None or devices is not None:
+                run_mesh = self._resolve_mesh(mesh, devices)
+            else:
+                run_mesh = self._default_mesh
+            with_gain = gain_scale is not None
+            with_drain = extra_drain is not None
+            rollout = self._rollout \
+                if (run_mesh is self._default_mesh
+                    and not with_gain and not with_drain) \
+                else self._rollout_fn(run_mesh, with_gain, with_drain)
+            inputs, valid = self._place(inputs, bdims, B, run_mesh)
+        with TraceAnnotation("rollout.scan"):
+            outs = jax.block_until_ready(rollout(*inputs))
+        with TraceAnnotation("rollout.fetch"):
+            outs = [np.asarray(x) for x in outs]
+        with TraceAnnotation("rollout.widen"):
+            (pos, active, charge, latency, power, feasible, cap_ok, assign,
+             lat_src, n_eff, e_tx, e_cmp) = outs
+
+            def tm(arr, dtype=np.float64):      # [T, B, ...] -> [B, T, ...]
+                return np.swapaxes(arr, 0, 1).astype(dtype)
+
+            return RolloutTrace(
+                latency=tm(latency), total_power=tm(power),
+                feasible=tm(feasible, bool), cap_feasible=tm(cap_ok, bool),
+                source_latency=tm(lat_src), assign=tm(assign, np.int64),
+                positions=tm(pos), active=tm(active, bool),
+                charge=tm(charge), n_requests=tm(n_eff, np.int64),
+                energy_tx=tm(e_tx), energy_cmp=tm(e_cmp), valid=valid)
+
+    def _host_inputs(self, base_positions, B, T, rng, charge0, alive0,
+                     forced_failures, sources, arrivals, waypoints, forced,
+                     gain_scale, extra_drain):
+        """``run``'s host draws and input validation: the rollout's
+        host-side inputs in argument order, with each one's batch axis
+        (0 for [B, ...], 1 for [T, B, ...])."""
+        U = len(self.devices)
         base = np.asarray(base_positions, np.float64)
         pos0 = np.broadcast_to(base, (B, U, 2)).astype(np.float32).copy() \
             if base.ndim == 2 else base.astype(np.float32)
@@ -386,65 +432,48 @@ class FleetRollout(ScenarioEngine):
         if alive0 is None:
             alive0 = np.ones((B, U), dtype=bool)
 
-        if mesh is not None or devices is not None:
-            run_mesh = self._resolve_mesh(mesh, devices)
-        else:
-            run_mesh = self._default_mesh
-        with_gain = gain_scale is not None
-        with_drain = extra_drain is not None
-        rollout = self._rollout \
-            if (run_mesh is self._default_mesh
-                and not with_gain and not with_drain) \
-            else self._rollout_fn(run_mesh, with_gain, with_drain)
-
-        valid = None
         inputs = [np.asarray(pos0, np.float32), charge0, alive0,
                   np.asarray(waypoints, np.float32), jitter, fail_u,
                   recov_u, forced, np.asarray(arrivals, np.float32)]
         bdims = [0, 0, 0, 0, 1, 1, 1, 1, 1]
-        if with_gain:
+        if gain_scale is not None:
             inputs.append(gain_scale)
             bdims.append(1)
-        if with_drain:
+        if extra_drain is not None:
             inputs.append(extra_drain)
             bdims.append(1)
+        return inputs, bdims
+
+    @staticmethod
+    def _place(inputs, bdims, B, run_mesh):
+        """The host-to-device placement of ``run``'s inputs, and the
+        [B] validity mask (None when no row is padding)."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
         if run_mesh is None:
-            inputs = [jnp.asarray(x) for x in inputs]
-        else:
-            # pad ragged B up to the mesh size with edge rows (real data,
-            # so the filler never produces NaN/inf surprises), record the
-            # validity mask, and place every input under its
-            # NamedSharding so the host->device transfer itself is already
-            # sharded — no full replica ever materializes on one device.
-            n_dev = run_mesh.devices.size
-            Bpad = pad_to_multiple(B, n_dev)
-            if Bpad != B:
-                pad = Bpad - B
-                inputs = [
-                    np.pad(x, [(0, pad) if d == bdim else (0, 0)
-                               for d in range(x.ndim)], mode="edge")
-                    for x, bdim in zip(inputs, bdims)]
-                valid = np.arange(Bpad) < B
-            axis = run_mesh.axis_names[0]
-            b_sh = NamedSharding(run_mesh, P(axis))
-            tb_sh = NamedSharding(run_mesh, P(None, axis))
-            inputs = [jax.device_put(x, b_sh if bdim == 0 else tb_sh)
-                      for x, bdim in zip(inputs, bdims)]
-
-        (pos, active, charge, latency, power, feasible, cap_ok, assign,
-         lat_src, n_eff, e_tx, e_cmp) = rollout(*inputs)
-
-        def tm(x, dtype=np.float64):        # [T, B, ...] -> [B, T, ...]
-            arr = np.asarray(x)
-            return np.swapaxes(arr, 0, 1).astype(dtype)
-
-        return RolloutTrace(
-            latency=tm(latency), total_power=tm(power),
-            feasible=tm(feasible, bool), cap_feasible=tm(cap_ok, bool),
-            source_latency=tm(lat_src), assign=tm(assign, np.int64),
-            positions=tm(pos), active=tm(active, bool), charge=tm(charge),
-            n_requests=tm(n_eff, np.int64),
-            energy_tx=tm(e_tx), energy_cmp=tm(e_cmp), valid=valid)
+            return [jnp.asarray(x) for x in inputs], None
+        # pad ragged B up to the mesh size with edge rows (real data, so
+        # the filler never produces NaN/inf surprises), record the
+        # validity mask, and place every input under its NamedSharding so
+        # the host->device transfer itself is already sharded — no full
+        # replica ever materializes on one device.
+        valid = None
+        n_dev = run_mesh.devices.size
+        Bpad = pad_to_multiple(B, n_dev)
+        if Bpad != B:
+            pad = Bpad - B
+            inputs = [
+                np.pad(x, [(0, pad) if d == bdim else (0, 0)
+                           for d in range(x.ndim)], mode="edge")
+                for x, bdim in zip(inputs, bdims)]
+            valid = np.arange(Bpad) < B
+        axis = run_mesh.axis_names[0]
+        b_sh = NamedSharding(run_mesh, P(axis))
+        tb_sh = NamedSharding(run_mesh, P(None, axis))
+        return [jax.device_put(x, b_sh if bdim == 0 else tb_sh)
+                for x, bdim in zip(inputs, bdims)], valid
 
 
 __all__ = ["FleetRollout", "RolloutTrace", "RolloutSpec"]

@@ -60,6 +60,7 @@ from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.runtime.chaos import FaultSchedule
 
@@ -615,8 +616,10 @@ class StreamingGateway:
         for k in range(n_windows):
             w = self._window
             self._window += 1
-            scheduled, arr = self._schedule_window(w)
-            self._ingest(w, source)
+            with TraceAnnotation("gateway.schedule"):
+                scheduled, arr = self._schedule_window(w)
+            with TraceAnnotation("gateway.ingest"):
+                self._ingest(w, source)
             if inflight is not None:
                 self._collect(*inflight)
             fut = self._dispatch(w, arr)
@@ -637,37 +640,38 @@ class StreamingGateway:
         """Deterministic served statistics (virtual-clock only — no
         wall-clock anywhere, so a replayed event stream reproduces this
         dict bitwise)."""
-        lats = np.asarray(sorted(r.latency_s for r in self.served),
-                          np.float64)
-        hit = sum(1 for r in self.served
-                  if (r.frame + 1) * self.config.frame_s <= r.deadline_s)
-        shed_total = sum(self.shed_counts.values())
-        horizon_s = self._window * self.config.window_frames * \
-            self.config.frame_s
-        return {
-            "submitted": len(self.requests),
-            "served": len(self.served),
-            "shed": {k: self.shed_counts[k]
-                     for k in sorted(self.shed_counts)},
-            "shed_total": shed_total,
-            "queued": len(self.queue),
-            "deadline_hit_rate": hit / len(self.served)
-            if self.served else 1.0,
-            "latency_p50_s": float(np.percentile(lats, 50))
-            if lats.size else float("nan"),
-            "latency_p99_s": float(np.percentile(lats, 99))
-            if lats.size else float("nan"),
-            "latency_mean_s": float(lats.mean())
-            if lats.size else float("nan"),
-            "windows": self.windows_completed + self.windows_failed,
-            "windows_failed": self.windows_failed,
-            "retries": self.retries,
-            "device_failures": self.device_failures,
-            "throughput_rps": len(self.served) / horizon_s
-            if horizon_s > 0 else 0.0,
-            "offered_rps": len(self.requests) / horizon_s
-            if horizon_s > 0 else 0.0,
-        }
+        with TraceAnnotation("gateway.report"):
+            lats = np.asarray(sorted(r.latency_s for r in self.served),
+                              np.float64)
+            hit = sum(1 for r in self.served
+                      if (r.frame + 1) * self.config.frame_s <= r.deadline_s)
+            shed_total = sum(self.shed_counts.values())
+            horizon_s = self._window * self.config.window_frames * \
+                self.config.frame_s
+            return {
+                "submitted": len(self.requests),
+                "served": len(self.served),
+                "shed": {k: self.shed_counts[k]
+                         for k in sorted(self.shed_counts)},
+                "shed_total": shed_total,
+                "queued": len(self.queue),
+                "deadline_hit_rate": hit / len(self.served)
+                if self.served else 1.0,
+                "latency_p50_s": float(np.percentile(lats, 50))
+                if lats.size else float("nan"),
+                "latency_p99_s": float(np.percentile(lats, 99))
+                if lats.size else float("nan"),
+                "latency_mean_s": float(lats.mean())
+                if lats.size else float("nan"),
+                "windows": self.windows_completed + self.windows_failed,
+                "windows_failed": self.windows_failed,
+                "retries": self.retries,
+                "device_failures": self.device_failures,
+                "throughput_rps": len(self.served) / horizon_s
+                if horizon_s > 0 else 0.0,
+                "offered_rps": len(self.requests) / horizon_s
+                if horizon_s > 0 else 0.0,
+            }
 
 
 __all__ = ["ArrivalSchedule", "DeviceStallError", "GatewayConfig",
