@@ -209,6 +209,7 @@ def bench_tropical_dp(B: int, M: int, L: int, S: int,
     from repro.kernels import autotune
     from repro.kernels.tropical_dp.ops import dp_wavefront_step
     from repro.kernels.tropical_dp.ref import dp_step_ref
+    from repro.kernels.tropical_dp.tropical_dp import to_lanes
     rng = np.random.default_rng(0)
     dp = rng.uniform(0, 10, (B, M, L, S + 1)).astype(np.float32)
     dp[:, :, 0, :] = np.inf
@@ -218,7 +219,9 @@ def bench_tropical_dp(B: int, M: int, L: int, S: int,
     tr0 = rng.uniform(0, 5, (B, M, S)).astype(np.float32)
     ct = rng.uniform(0, 2, (L, S)).astype(np.float32)
     ok = (rng.random((L, S)) > 0.1).astype(np.float32)
-    args = tuple(jnp.asarray(x) for x in (dp, tr, tr0, ct, ok))
+    # the kernel's layout: scenarios last, split into 128-lane rows
+    last = (lambda x: to_lanes(jnp.moveaxis(jnp.asarray(x), 0, -1)))
+    args = (last(dp), last(tr), last(tr0), jnp.asarray(ct), jnp.asarray(ok))
     ref_t, ker_t, agree = _time_paths(
         jax.jit(dp_step_ref),
         jax.jit(functools.partial(dp_wavefront_step, use_kernel=True)),

@@ -378,18 +378,29 @@ def _chain_dp_solve_kernelized(compute: jnp.ndarray, memory: jnp.ndarray,
     """Kernel-path chain DP: the Pallas tropical wavefront step with a
     native source-slot axis.
 
-    Same recurrence and tie-breaks as ``_chain_dp_solve`` — the operand
-    prep, backward scan and masks are that function's code verbatim; only
-    the forward-step relaxation is swapped for
+    Same recurrence, values and tie-breaks as ``_chain_dp_solve`` — the
+    masks and the backward scan are that function's code; only the
+    forward-step relaxation is swapped for
     ``kernels.tropical_dp.dp_wavefront_step``.  ``sources`` carries a slot
     axis [B, M] so the multi-source planner shares ONE kernel launch per
     step across every (scenario, slot) pair: the transfer tensor ``tr`` is
     source-independent (its a = 0 row is dead — the kernel folds the
     per-slot source row ``tr0`` in-register instead of the oracle's
-    ``tr_src`` overwrite).  Returns ``(assign [B, M, L], latency [B, M])``,
-    bitwise-identical to vmapping ``_chain_dp_solve`` over the slot axis.
+    ``tr_src`` overwrite).
+
+    Layout: the kernel's, scenarios last and split into lane rows
+    ``(Bt, C)`` (``tropical_dp.lane_split``, padded with inf scenarios
+    that are sliced off).  ``tr`` [L, S, S+1, Bt, C] and ``tr0``
+    [M, S, Bt, C] are built once per solve; the scan carries the table
+    ``dp`` [M, L+1, S+1, Bt, C], hands it to the kernel whole (the kernel
+    reads rows 0..L-1) and writes each new row in place, so nothing is
+    copied or transposed per step.  The parent pointers are transposed
+    once, after the scan, for the backtrack.  Returns
+    ``(assign [B, M, L], latency [B, M])``, bitwise-identical to vmapping
+    ``_chain_dp_solve`` over the slot axis.
     """
     from repro.kernels.tropical_dp.ops import dp_wavefront_step
+    from repro.kernels.tropical_dp.tropical_dp import from_lanes, to_lanes
     L = compute.shape[0]
     S = len(order)
     B, M = sources.shape
@@ -404,27 +415,30 @@ def _chain_dp_solve_kernelized(compute: jnp.ndarray, memory: jnp.ndarray,
     mem_cap_o = mem_cap[order_arr]                                  # [S]
     cmp_cap_o = compute_cap[order_arr]
     thr_o = throughput[order_arr]
-    active_o = active[:, order_arr]                                 # [B, S]
+    active_t = active[:, order_arr].T                               # [S, B]
 
-    # Slot-invariant transfer tensor: identical to _chain_dp_solve's except
-    # the a = 0 row keeps its (dead) placeholder — the kernel overrides that
-    # row with tr0, so one tr serves every source slot.
+    # Slot-invariant transfer tensor: _chain_dp_solve's values, scenarios
+    # last, except that the a = 0 row keeps its (dead) placeholder — the
+    # kernel overrides that row with tr0, so one tr serves every slot.
     prev_dev = jnp.concatenate([jnp.zeros(1, jnp.int32), order_arr])
-    r_prev = rate[:, prev_dev[:, None], order_arr[None, :]]         # [B,S+1,S]
-    tr = jnp.where(r_prev[:, None, :, :] > 0,
-                   bits_in[None, :, None, None] / r_prev[:, None, :, :],
-                   INF)                                             # [B,L,S+1,S]
-    s0_lt_s = (jnp.arange(S + 1)[:, None]
-               < jnp.arange(1, S + 1)[None, :])                     # [S+1, S]
-    tr = jnp.where(s0_lt_s[None, None] & active_o[:, None, None, :],
+    rate_t = jnp.moveaxis(rate, 0, -1)                              # [U,U,B]
+    r_prev = rate_t[prev_dev[None, :], order_arr[:, None]]          # [S,S+1,B]
+    tr = jnp.where(r_prev[None] > 0,
+                   bits_in[:, None, None, None] / r_prev[None],
+                   INF)                                             # [L,S,S+1,B]
+    s0_lt_s = (jnp.arange(S + 1)[None, :]
+               < jnp.arange(1, S + 1)[:, None])                     # [S, S+1]
+    tr = jnp.where(s0_lt_s[None, :, :, None] & active_t[None, :, None, :],
                    tr, INF)
-    tr = tr.swapaxes(2, 3)                                          # [B,L,S,S+1]
+    tr = to_lanes(tr)                                               # [..,Bt,C]
     # per-slot source row, masked exactly like the oracle's tr_src at s0 = 0
-    r_src = rate[jnp.arange(B)[:, None], sources][:, :, order_arr]  # [B, M, S]
+    r_src = rate_t[sources.T[:, None, :], order_arr[None, :, None],
+                   jnp.arange(B)]                                   # [M, S, B]
     tr_src = jnp.where(r_src > 0, input_bits / r_src, INF)
-    tr0 = jnp.where(active_o[:, None, :], tr_src, INF)              # [B, M, S]
+    tr0 = to_lanes(jnp.where(active_t[None], tr_src, INF))          # [..,Bt,C]
 
-    dp0 = jnp.full((B, M, L + 1, S + 1), INF).at[:, :, 0, 0].set(0.0)
+    dp0 = jnp.full((M, L + 1, S + 1) + tr0.shape[-2:], INF)
+    dp0 = dp0.at[:, 0, 0].set(0.0)
 
     def forward(dp, b):
         blk_c = pre_c[b] - pre_c[:L]                                # [L] (a)
@@ -434,23 +448,26 @@ def _chain_dp_solve_kernelized(compute: jnp.ndarray, memory: jnp.ndarray,
               (a_ix < b)[:, None])                                  # [L, S]
         ct = blk_c[:, None] / thr_o[None, :]                        # [L, S]
         row, pa, ps = dp_wavefront_step(
-            dp[:, :, :L], tr, tr0, ct.astype(jnp.float32),
-            ok.astype(jnp.float32))                                 # [B, M, S]
-        dp = dp.at[:, :, b, :].set(
-            jnp.concatenate([jnp.full((B, M, 1), INF), row], -1))
-        pad = jnp.zeros((B, M, 1), jnp.int32)
-        return dp, (jnp.concatenate([pad, pa], -1),
-                    jnp.concatenate([pad, ps], -1))
+            dp, tr, tr0, ct.astype(jnp.float32),
+            ok.astype(jnp.float32))                                 # [M,S,Bt,C]
+        dp = jax.lax.dynamic_update_slice(dp, row[:, None],
+                                          (0, b, 1, 0, 0))
+        return dp, (pa, ps)
 
     dp, (pa, ps) = jax.lax.scan(forward, dp0, jnp.arange(1, L + 1))
-    # backtrack on R = B * M flattened rows — _chain_dp_solve's reverse
-    # scan verbatim
+    # backtrack on R = B * M flattened (scenario, slot) rows —
+    # _chain_dp_solve's reverse scan verbatim
     R = B * M
-    final = dp[:, :, L, :].reshape(R, S + 1)                        # [R, S+1]
+    final = jnp.moveaxis(from_lanes(dp[:, L], B), -1, 0)            # [B,M,S+1]
+    final = final.reshape(R, S + 1)
     s_best = jnp.argmin(final, 1).astype(jnp.int32)
     latency = final.min(1)
-    pa = pa.reshape(L, R, S + 1)
-    ps = ps.reshape(L, R, S + 1)
+
+    def parent_rows(p):              # [L, M, S, Bt, C] -> [L, R, S+1]
+        p = jnp.moveaxis(from_lanes(p, B), -1, 1).reshape(L, R, S)
+        return jnp.concatenate([jnp.zeros((L, R, 1), jnp.int32), p], -1)
+
+    pa, ps = parent_rows(pa), parent_rows(ps)
     rows = jnp.arange(R)
 
     def backward(carry, j):

@@ -24,21 +24,24 @@ from typing import Dict, Optional
 from repro.kernels import default_backend
 
 #: (kernel, backend[, U, L, S, dtype]) -> block dict.  0 = whole axis.
-#: No row has been tuned by a measurement yet: the CPU rows follow from
-#: interpret mode's sequential grid, and the TPU rows are the smallest
-#: tiles that the v5e compiler accepts and that fit the default scoped
-#: VMEM at the benchmark shapes (``tests/test_tpu_compile.py`` compiles
-#: them).  Shape-keyed rows override a backend row for one shape.
+#: The CPU rows follow from interpret mode's sequential grid.  The DP
+#: kernel's TPU row was chosen by timing on one TPU v5e (below); the link
+#: geometry TPU row is the smallest tile that the v5e compiler accepts at
+#: the benchmark shapes (``tests/test_tpu_compile.py`` compiles both).
+#: Shape-keyed rows override a backend row for one shape.
 TABLE: Dict[tuple, Dict[str, int]] = {
     # CPU = interpret mode: one grid cell, fully vectorized body.
     ("tropical_dp", "cpu"): {"block_b": 0, "block_m": 0, "block_s": 0},
     ("link_geometry", "cpu"): {"block_b": 0, "block_u": 0},
-    # TPU: up to 8 scenarios per DP cell (fewer where the candidate slab
-    # would overflow VMEM), the whole slot axis up to 8 slots (tr0 and
-    # the outputs put M on sublanes) and the whole state axis (S sits on
-    # lanes: whole or a multiple of 128); link geometry tiles 8 scenarios
-    # (B sits on sublanes of the [B, U] planes) by whole rows.
-    ("tropical_dp", "tpu"): {"block_b": 8, "block_m": 8, "block_s": 0},
+    # TPU DP: 1024 scenarios (one (8, 128) vreg) x 2 slots x every state
+    # per cell.  Timed on one v5e at the AlexNet cell's step (B = 8192,
+    # M = 8, L = 11, S = 8, operands in HBM), 352 launches in a scan, over
+    # block_b 1024..8192 x block_m 1, 2, 4, 8 x block_s 8, 4, 2: 73.4 us a
+    # launch, against 74.5 us at 4 slots, 88.7 us at 1 slot, 95-105 us at
+    # 8 slots or 2048 scenarios, 104-308 us for larger scenario tiles and
+    # 104-220 us with the state axis split.  Link geometry tiles 8
+    # scenarios (B sits on sublanes of the [B, U] planes) by whole rows.
+    ("tropical_dp", "tpu"): {"block_b": 1024, "block_m": 2, "block_s": 0},
     ("link_geometry", "tpu"): {"block_b": 8, "block_u": 0},
     # GPU (Triton) runs interpret today as well — same shape as CPU.
     ("tropical_dp", "gpu"): {"block_b": 0, "block_m": 0, "block_s": 0},

@@ -15,11 +15,14 @@ def dp_wavefront_step(dp: jnp.ndarray, tr: jnp.ndarray, tr0: jnp.ndarray,
                       interpret: bool | None = None):
     """One chain-DP wavefront step over every (scenario, source slot).
 
-    ``dp`` [B, M, L, S+1], ``tr`` [B, L, S, S+1] (a = 0 row dead),
-    ``tr0`` [B, M, S], ``ct``/``ok`` [L, S] -> (row, pa, ps), each
-    [B, M, S].  ``use_kernel`` selects the block-tiled Pallas kernel
-    (interpret-mode on CPU via ``resolve_interpret``) or the jnp oracle;
-    both are bitwise-identical (tested).
+    One contract for kernel and oracle, scenarios last and split as
+    ``(R, C)`` (``tropical_dp.lane_split`` / ``to_lanes``):
+    ``dp`` [M, L', S+1, R, C] (rows 0..L-1 read), ``tr`` [L, S, S+1, R, C]
+    (a = 0 row dead), ``tr0`` [M, S, R, C], ``ct``/``ok`` [L, S] ->
+    (row, pa, ps), each [M, S, R, C].  ``use_kernel`` selects the
+    block-tiled Pallas kernel (interpret-mode on CPU via
+    ``resolve_interpret``) or the jnp oracle; both are bitwise-identical
+    (tested).
     """
     if use_kernel:
         return tropical_dp_step(dp, tr, tr0, ct, ok, block_b=block_b,

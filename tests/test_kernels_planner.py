@@ -28,7 +28,8 @@ from repro.kernels import autotune, default_backend, resolve_interpret
 from repro.kernels.link_geometry.ops import fused_link_geometry
 from repro.kernels.link_geometry.ref import link_geometry_ref
 from repro.kernels.tropical_dp.ops import dp_wavefront_step
-from repro.kernels.tropical_dp.ref import dp_step_ref
+from repro.kernels.tropical_dp.ref import dp_step_ref, dp_step_rows
+from repro.kernels.tropical_dp.tropical_dp import from_lanes, to_lanes
 
 PARAMS = RadioParams()
 INF = np.inf
@@ -39,11 +40,23 @@ INF = np.inf
 # ---------------------------------------------------------------------------
 
 
+def lanes(dp, tr, tr0, ct, ok):
+    """Scenario-first step operands -> the kernel's layout (scenarios last,
+    split (R, C) and inf-padded: ``tropical_dp.to_lanes``)."""
+    last = (lambda x: to_lanes(jnp.moveaxis(jnp.asarray(x), 0, -1)))
+    return [last(dp), last(tr), last(tr0), jnp.asarray(ct), jnp.asarray(ok)]
+
+
+def scenario_rows(out, B):
+    """Kernel-layout results [M, S, R, C] -> scenario-first [B, M, S]."""
+    return np.moveaxis(np.asarray(from_lanes(out, B)), -1, 0)
+
+
 def dp_step_operands(seed, B=3, M=2, L=5, S=4, dead_frac=0.15):
     """Random wavefront-step operands with the solver's structure: dp row 0
     = [0, inf...], dead a = 0 row in tr, a sprinkling of inf (dead UAV /
     infeasible link) entries, and a coarse value grid so ties occur
-    naturally on top of the crafted ones."""
+    naturally on top of the crafted ones.  In the kernel's layout."""
     rng = np.random.default_rng(seed)
     dp = rng.integers(0, 8, (B, M, L, S + 1)).astype(np.float32)
     dp[:, :, 0, :] = INF
@@ -56,7 +69,31 @@ def dp_step_operands(seed, B=3, M=2, L=5, S=4, dead_frac=0.15):
     dp[:, :, 0, 0] = 0.0
     ct = rng.integers(0, 3, (L, S)).astype(np.float32)
     ok = (rng.random((L, S)) > 0.25).astype(np.float32)
-    return [jnp.asarray(x) for x in (dp, tr, tr0, ct, ok)]
+    return lanes(dp, tr, tr0, ct, ok)
+
+
+def crafted_step_operands(seed, B, M, L, S):
+    """Operands whose scenarios cycle through three crafted kinds: all-inf
+    (no finite candidate at any a, the source row dead too), exact ties
+    across every s0 AND every a (each candidate costs 6), and dense ties
+    from values in {0, 1}.  Every (a, s) is feasible, so the ties reach
+    the outer min."""
+    rng = np.random.default_rng(seed)
+    dp = rng.integers(0, 2, (B, M, L, S + 1)).astype(np.float32)
+    tr = rng.integers(0, 2, (B, L, S, S + 1)).astype(np.float32)
+    tr0 = rng.integers(0, 2, (B, M, S)).astype(np.float32)
+    kind = np.arange(B) % 3
+    dp[kind == 0] = INF
+    tr0[kind == 0] = INF
+    dp[kind == 1, :, :, :] = 2.0
+    tr[kind == 1] = 4.0
+    tr0[kind == 1] = 6.0
+    dp[:, :, 0, :] = INF
+    dp[:, :, 0, 0] = 0.0
+    tr[:, 0] = INF
+    ct = np.zeros((L, S), np.float32)
+    ok = np.ones((L, S), np.float32)
+    return lanes(dp, tr, tr0, ct, ok)
 
 
 def geometry_operands(seed, B=4, U=6, with_gain=True, dead=True):
@@ -93,21 +130,48 @@ class TestTropicalDpStep:
         self.assert_step_parity(dp_step_operands(seed))
 
     @pytest.mark.parametrize("shape", [(1, 1, 2, 2), (2, 4, 3, 5),
-                                       (5, 1, 8, 3), (2, 3, 4, 8)])
+                                       (5, 1, 8, 3), (2, 3, 4, 8),
+                                       # the AlexNet cell's step: 8 slots,
+                                       # L = 11, S = 8, two lane rows
+                                       (256, 8, 11, 8),
+                                       # B off the 128-lane multiple
+                                       # (inf-padded), with 8 and 1 slots
+                                       (200, 8, 11, 8), (200, 1, 11, 8)])
     def test_bitwise_parity_shapes(self, shape):
         B, M, L, S = shape
         self.assert_step_parity(dp_step_operands(99, B=B, M=M, L=L, S=S))
 
-    @pytest.mark.parametrize("blocks", [dict(block_b=1),
+    @pytest.mark.parametrize("shape", [(3, 2, 5, 4), (256, 8, 11, 8),
+                                       (200, 1, 11, 8)])
+    def test_bitwise_parity_crafted(self, shape):
+        """All-inf scenarios and exact ties in both s0 and a, scenario by
+        scenario across the lanes: the running minima must keep the
+        oracle's first-argmin and all-inf -> index 0 conventions."""
+        B, M, L, S = shape
+        args = crafted_step_operands(13, B, M, L, S)
+        self.assert_step_parity(args)
+        row, pa, ps = (scenario_rows(o, B) for o in
+                       dp_wavefront_step(*args, use_kernel=True))
+        dead, tied = np.arange(B) % 3 == 0, np.arange(B) % 3 == 1
+        assert np.isinf(row[dead]).all()
+        np.testing.assert_array_equal(pa[dead], 0)
+        np.testing.assert_array_equal(ps[dead], 0)
+        # every candidate costs 6: the source row (a = 0, s0 = 0) wins
+        np.testing.assert_array_equal(row[tied], 6.0)
+        np.testing.assert_array_equal(pa[tied], 0)
+        np.testing.assert_array_equal(ps[tied], 0)
+
+    @pytest.mark.parametrize("blocks", [dict(block_b=128),
                                         dict(block_m=1),
                                         dict(block_s=2),
-                                        dict(block_b=1, block_m=1,
+                                        dict(block_b=128, block_m=1,
                                              block_s=2),
                                         dict(block_s=3)])  # snaps 3 -> 2
     def test_tiled_grids_match(self, blocks):
         """Multi-cell grids (interpret mode runs them sequentially) emit
-        the same tiles as the whole-axis launch."""
-        self.assert_step_parity(dp_step_operands(7, B=2, M=2, L=4, S=4),
+        the same tiles as the whole-axis launch; B = 256 is two lane rows,
+        so 128 scenarios a cell tiles the scenario axis."""
+        self.assert_step_parity(dp_step_operands(7, B=256, M=2, L=4, S=4),
                                 **blocks)
 
     def test_first_argmin_tie_breaks(self):
@@ -125,19 +189,21 @@ class TestTropicalDpStep:
         tr0 = np.full((B, M, S), 5.0, np.float32)
         ct = np.zeros((L, S), np.float32)
         ok = np.ones((L, S), np.float32)
-        args = [jnp.asarray(x) for x in (dp, tr, tr0, ct, ok)]
-        row_k, pa_k, ps_k = dp_wavefront_step(*args, use_kernel=True)
+        args = lanes(dp, tr, tr0, ct, ok)
+        row_k, pa_k, ps_k = (scenario_rows(o, B) for o in
+                             dp_wavefront_step(*args, use_kernel=True))
         # candidates: a=0 -> 0+5=5; a=1 -> 2+3=5; a=2 -> 1+4=5: a=0 wins
-        np.testing.assert_array_equal(np.asarray(row_k)[0, 0], 5.0)
-        np.testing.assert_array_equal(np.asarray(pa_k)[0, 0], 0)
-        np.testing.assert_array_equal(np.asarray(ps_k)[0, 0], 0)
+        np.testing.assert_array_equal(row_k[0, 0], 5.0)
+        np.testing.assert_array_equal(pa_k[0, 0], 0)
+        np.testing.assert_array_equal(ps_k[0, 0], 0)
         # kill the a=0 candidate: a=1 wins over the equal a=2, s0 first-min
         tr0[:] = INF
-        args[2] = jnp.asarray(tr0)
+        args = lanes(dp, tr, tr0, ct, ok)
         self.assert_step_parity(args)
-        row_k, pa_k, ps_k = dp_wavefront_step(*args, use_kernel=True)
-        np.testing.assert_array_equal(np.asarray(pa_k)[0, 0], 1)
-        np.testing.assert_array_equal(np.asarray(ps_k)[0, 0], 1)
+        row_k, pa_k, ps_k = (scenario_rows(o, B) for o in
+                             dp_wavefront_step(*args, use_kernel=True))
+        np.testing.assert_array_equal(pa_k[0, 0], 1)
+        np.testing.assert_array_equal(ps_k[0, 0], 1)
 
     def test_all_infeasible_matches_oracle(self):
         """Fully masked steps (dead fleet) keep argmin's all-inf -> index 0
@@ -148,6 +214,23 @@ class TestTropicalDpStep:
         row_k, pa_k, ps_k = dp_wavefront_step(*args, use_kernel=True)
         assert np.isinf(np.asarray(row_k)).all()
         np.testing.assert_array_equal(np.asarray(pa_k), 0)
+
+    def test_ref_lifts_the_solver_step(self):
+        """``dp_step_ref`` in the kernel's layout is the scenario-first
+        step ``dp_step_rows`` moved by transposes, padding sliced off."""
+        B, M, L, S = 200, 2, 6, 5
+        rng = np.random.default_rng(21)
+        dp = rng.integers(0, 8, (B, M, L, S + 1)).astype(np.float32)
+        dp[:, :, 0, :] = INF
+        dp[:, :, 0, 0] = 0.0
+        tr = rng.integers(0, 5, (B, L, S, S + 1)).astype(np.float32)
+        tr0 = rng.integers(0, 5, (B, M, S)).astype(np.float32)
+        ct = rng.integers(0, 3, (L, S)).astype(np.float32)
+        ok = (rng.random((L, S)) > 0.25).astype(np.float32)
+        rows = jax.jit(dp_step_rows)(dp, tr, tr0, ct, ok)
+        lifted = self.REF(*lanes(dp, tr, tr0, ct, ok))
+        for a, b in zip(lifted, rows):
+            np.testing.assert_array_equal(scenario_rows(a, B), np.asarray(b))
 
     def test_compiled_mode_or_skip(self):
         """interpret=False must agree bitwise wherever the backend compiles
@@ -308,6 +391,21 @@ class TestSolverKernelPath:
                 p["sources"], p["active"])
         a0, l0 = solve_chain_dp_multisource(*args)
         a1, l1 = solve_chain_dp_multisource(*args, use_kernel=True)
+        np.testing.assert_array_equal(a1, a0)
+        np.testing.assert_array_equal(l1, l0)
+
+    @pytest.mark.parametrize("multi", [False, True])
+    def test_ragged_batch_bitwise(self, multi):
+        """B = 200 is padded to two 128-lane rows of inf scenarios inside
+        the kernel path; the padding must not reach the plans."""
+        p = dp_problem(8, B=200)
+        args = (p["compute"], p["memory"], p["act_bits"], p["input_bits"],
+                p["mem_cap"], p["compute_cap"], p["throughput"], p["rate"],
+                p["sources"] if multi else p["source"], p["active"])
+        solve = solve_chain_dp_multisource if multi else \
+            solve_chain_dp_batched
+        a0, l0 = solve(*args)
+        a1, l1 = solve(*args, use_kernel=True)
         np.testing.assert_array_equal(a1, a0)
         np.testing.assert_array_equal(l1, l0)
 

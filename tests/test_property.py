@@ -494,6 +494,7 @@ class TestKernelParityProperties:
     def test_tropical_dp_step_bitwise(self, shape_i, seed, dead_frac):
         import jax.numpy as jnp
         from repro.kernels.tropical_dp.ops import dp_wavefront_step
+        from repro.kernels.tropical_dp.tropical_dp import to_lanes
         dp_ref, _ = self._refs()
         B, M, L, S = self._DP_SHAPES[shape_i]
         rng = np.random.default_rng(seed)
@@ -507,7 +508,9 @@ class TestKernelParityProperties:
         tr[:, 0] = np.inf
         ct = rng.integers(0, 3, (L, S)).astype(np.float32)
         ok = (rng.random((L, S)) > dead_frac).astype(np.float32)
-        args = [jnp.asarray(x) for x in (dp, tr, tr0, ct, ok)]
+        last = (lambda x: to_lanes(jnp.moveaxis(jnp.asarray(x), 0, -1)))
+        args = [last(dp), last(tr), last(tr0), jnp.asarray(ct),
+                jnp.asarray(ok)]
         ref = dp_ref(*args)
         got = dp_wavefront_step(*args, use_kernel=True)
         for a, b in zip(got, ref):

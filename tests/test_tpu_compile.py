@@ -20,7 +20,7 @@ import repro.kernels as kernels
 from repro.core.channel import RadioParams
 from repro.kernels import autotune
 from repro.kernels.link_geometry.link_geometry import link_geometry
-from repro.kernels.tropical_dp.tropical_dp import tropical_dp_step
+from repro.kernels.tropical_dp.tropical_dp import lane_split, tropical_dp_step
 
 PARAMS = RadioParams()
 F32 = jnp.float32
@@ -58,17 +58,25 @@ def compiled_text(fn, one_chip, *shapes) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("U,L,M", [(8, 7, 1), (8, 7, 2), (32, 32, 32)])
-def test_tropical_dp_compiles(one_chip, U, L, M):
-    B, S = 64, U
+@pytest.mark.parametrize("U,L,M,B", [
+    pytest.param(8, 7, 1, 64, id="8-7-1"),
+    pytest.param(8, 7, 2, 64, id="8-7-2"),
+    # U = L = 32 takes one state per cell and the raised VMEM limit
+    pytest.param(32, 32, 32, 64, id="32-32-32"),
+    # the AlexNet kernel cell's step: 8 slots, L = 11, B = 8192 on lanes
+    pytest.param(8, 11, 8, 8192, id="8-11-8")])
+def test_tropical_dp_compiles(one_chip, U, L, M, B):
+    S = U
+    R, C = lane_split(B)
     blocks = autotune.lookup("tropical_dp", U=U, L=L, S=S, backend="tpu")
 
     def step(dp, tr, tr0, ct, ok):
         return tropical_dp_step(dp, tr, tr0, ct, ok, interpret=False,
                                 **blocks)
 
-    text = compiled_text(step, one_chip, ((B, M, L, S + 1), F32),
-                         ((B, L, S, S + 1), F32), ((B, M, S), F32),
+    # the whole [L+1]-row table, as the solver's scan passes it
+    text = compiled_text(step, one_chip, ((M, L + 1, S + 1, R, C), F32),
+                         ((L, S, S + 1, R, C), F32), ((M, S, R, C), F32),
                          ((L, S), F32), ((L, S), F32))
     assert "tpu_custom_call" in text
 
