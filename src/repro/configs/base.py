@@ -167,13 +167,20 @@ class ArchConfig:
 
 
 # ---------------------------------------------------------------------------
-# CNN config (the paper's own models: LeNet / AlexNet)
+# CNN config (the paper's own models, LeNet / AlexNet, and ResNet-50)
 # ---------------------------------------------------------------------------
 
 
 @dataclass(frozen=True)
 class ConvLayerSpec:
-    """One CNN layer in the paper's eq (1)-(3) parameterization."""
+    """One CNN layer in the paper's eq (1)-(3) parameterization.
+
+    A residual block is a run of units from one that opens a side path
+    (``residual='open'``: the unit's input is kept, or projected by a
+    ``proj_channels``-wide 1x1 conv at the unit's own stride) to one that
+    closes it (``residual='close'``: the side path is added before the
+    unit's ReLU).  Between the two, every cut carries the side path as
+    well."""
 
     name: str
     kind: str                   # 'conv' | 'pool' | 'fc'
@@ -185,6 +192,9 @@ class ConvLayerSpec:
     out_spatial: int = 0        # z_j (computed if 0)
     in_features: int = 0        # fc: n_{j-1}
     out_features: int = 0       # fc: n_j
+    pool_op: str = "max"        # pool: 'max' | 'avg'
+    residual: str = ""          # '' | 'open' | 'close'
+    proj_channels: int = 0      # on 'open': projection width (0 = identity)
 
 
 @dataclass(frozen=True)

@@ -18,13 +18,14 @@ _MODULES: Dict[str, str] = {
     "qwen2-vl-2b": "repro.configs.qwen2_vl_2b",
     "granite-moe-1b-a400m": "repro.configs.granite_moe_1b_a400m",
     "olmoe-1b-7b": "repro.configs.olmoe_1b_7b",
-    # the paper's own CNNs
+    # the paper's own CNNs, and ResNet-50
     "lenet": "repro.configs.lenet",
     "alexnet": "repro.configs.alexnet",
+    "resnet50": "repro.configs.resnet50",
 }
 
-LM_ARCHS = tuple(k for k in _MODULES if k not in ("lenet", "alexnet"))
-CNN_ARCHS = ("lenet", "alexnet")
+CNN_ARCHS = ("lenet", "alexnet", "resnet50")
+LM_ARCHS = tuple(k for k in _MODULES if k not in CNN_ARCHS)
 ALL_ARCHS = tuple(_MODULES)
 
 

@@ -414,7 +414,10 @@ def _chain_dp_solve_kernelized(compute: jnp.ndarray, memory: jnp.ndarray,
 
     mem_cap_o = mem_cap[order_arr]                                  # [S]
     cmp_cap_o = compute_cap[order_arr]
-    thr_o = throughput[order_arr]
+    # a runtime value on both DP paths: folded into a constant, the
+    # division by it below may become a multiply by its reciprocal in one
+    # program and not the other (1-ulp latencies apart at L = 52)
+    thr_o = jax.lax.optimization_barrier(throughput[order_arr])
     active_t = active[:, order_arr].T                               # [S, B]
 
     # Slot-invariant transfer tensor: _chain_dp_solve's values, scenarios
@@ -533,7 +536,10 @@ def _chain_dp_solve(compute: jnp.ndarray, memory: jnp.ndarray,
 
     mem_cap_o = mem_cap[order_arr]                                  # [S]
     cmp_cap_o = compute_cap[order_arr]
-    thr_o = throughput[order_arr]
+    # a runtime value on both DP paths: folded into a constant, the
+    # division by it below may become a multiply by its reciprocal in one
+    # program and not the other (1-ulp latencies apart at L = 52)
+    thr_o = jax.lax.optimization_barrier(throughput[order_arr])
     active_o = active[:, order_arr]                                 # [B, S]
 
     # Transfer into a block on device order[s-1] from predecessor state s0:

@@ -30,6 +30,8 @@ class LayerCost:
     kind: str = "layer"
     # decode-time state carried between steps (KV cache / recurrent state)
     state_bytes: float = 0.0
+    # the part of act_bits that is an open residual side path (K_skip)
+    skip_bits: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -57,29 +59,44 @@ def _conv_out(in_spatial: int, k: int, stride: int, pad: int) -> int:
 
 
 def cnn_cost(cfg: CNNConfig, act_bits_per_elem: int = 32) -> ModelCost:
-    """Per-layer (c_j, m_j, K_j) for a CNN per eq. (1)-(3)."""
+    """Per-layer (c_j, m_j, K_j) for a CNN per eq. (1)-(3).
+
+    Residual blocks (``ConvLayerSpec.residual``): the unit that opens a
+    block also computes its 1x1 projection shortcut, if it has one, and
+    pays for it by eq. (1)/(3); every cut from that unit up to the one
+    that closes the block carries the side path beside the main
+    activation, ``K'_j = K_j + K_skip`` (the block's input for an identity
+    shortcut, the projection's output otherwise).  The side path is
+    relayed along the chain, so a cut's price depends on j alone."""
     layers: List[LayerCost] = []
     spatial = cfg.input_hw
     channels = cfg.input_channels
     flat: Optional[int] = None
+    skip: Optional[Tuple[int, int]] = None    # open side path (channels, z)
     for spec in cfg.layers:
+        z_in, c_in = spatial, channels          # the unit's input map
         if spec.kind == "conv":
             z = spec.out_spatial or _conv_out(spatial, spec.kernel,
                                               spec.stride, spec.padding)
             n_prev, n_j, s_j = spec.in_channels or channels, spec.out_channels, spec.kernel
             flops = float(n_prev) * s_j ** 2 * n_j * z ** 2        # eq. (1)
             weights = float(n_prev) * s_j ** 2 * n_j + n_j          # + bias
+            if spec.residual == "open" and spec.proj_channels:
+                n_p = spec.proj_channels
+                z_p = _conv_out(z_in, 1, spec.stride, 0)
+                flops += float(n_prev) * n_p * z_p ** 2             # eq. (1)
+                weights += float(n_prev) * n_p + n_p
             act = float(n_j) * z ** 2 * act_bits_per_elem
-            layers.append(LayerCost(spec.name, flops,
-                                    weights * cfg.weight_bits / 8.0, act, "conv"))
+            kind = "conv"
             spatial, channels = z, n_j
         elif spec.kind == "pool":
             z = spec.out_spatial or _conv_out(spatial, spec.kernel,
                                               spec.stride, spec.padding)
             # pooling: comparisons only; the paper folds these into the conv
             # layer's UAV, so cost ~ 0 compute, 0 weights.
+            flops, weights = 0.0, 0.0
             act = float(channels) * z ** 2 * act_bits_per_elem
-            layers.append(LayerCost(spec.name, 0.0, 0.0, act, "pool"))
+            kind = "pool"
             spatial = z
         elif spec.kind == "fc":
             n_prev = spec.in_features or (flat if flat is not None
@@ -88,11 +105,25 @@ def cnn_cost(cfg: CNNConfig, act_bits_per_elem: int = 32) -> ModelCost:
             flops = float(n_prev) * n_j                             # eq. (2)
             weights = float(n_prev) * n_j + n_j
             act = float(n_j) * act_bits_per_elem
-            layers.append(LayerCost(spec.name, flops,
-                                    weights * cfg.weight_bits / 8.0, act, "fc"))
+            kind = "fc"
             flat = n_j
         else:
             raise ValueError(f"unknown layer kind {spec.kind}")
+        if spec.residual == "open":
+            skip = ((spec.proj_channels,
+                     _conv_out(z_in, 1, spec.stride, 0))
+                    if spec.proj_channels else (c_in, z_in))
+        elif spec.residual == "close":
+            if skip != (channels, spatial):
+                raise ValueError(f"{spec.name}: side path {skip} does not "
+                                 f"match the block's output "
+                                 f"{(channels, spatial)}")
+            skip = None
+        skip_bits = 0.0 if skip is None else \
+            float(skip[0]) * skip[1] ** 2 * act_bits_per_elem
+        layers.append(LayerCost(spec.name, flops,
+                                weights * cfg.weight_bits / 8.0,
+                                act + skip_bits, kind, skip_bits=skip_bits))
     input_bits = float(cfg.input_hw ** 2 * cfg.input_channels * 8)  # 8-bit px
     return ModelCost(cfg.name, tuple(layers), input_bits)
 

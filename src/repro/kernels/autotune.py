@@ -42,6 +42,12 @@ TABLE: Dict[tuple, Dict[str, int]] = {
     # 104-220 us with the state axis split.  Link geometry tiles 8
     # scenarios (B sits on sublanes of the [B, U] planes) by whole rows.
     ("tropical_dp", "tpu"): {"block_b": 1024, "block_m": 2, "block_s": 0},
+    # At ResNet-50's 52-unit chain (B = 4096, M = 8, L = 52, S = 8) the
+    # row above does not fit the VMEM budget; ``_tiles`` shrinks it to 1
+    # slot x 2 states, 481 us a launch on one v5e.  Under a raised 64 MiB
+    # limit 2 slots x 4 states read 451 us, 2048 scenarios x 1 x 2 472 us,
+    # other splits of 1-8 slots x 1-8 states 459-477 us; 2 slots x 1
+    # state, which shrinking the states first gives, 829 us.
     ("link_geometry", "tpu"): {"block_b": 8, "block_u": 0},
     # GPU (Triton) runs interpret today as well — same shape as CPU.
     ("tropical_dp", "gpu"): {"block_b": 0, "block_m": 0, "block_s": 0},

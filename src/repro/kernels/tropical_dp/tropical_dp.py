@@ -165,9 +165,14 @@ def _tiles(M: int, R: int, C: int, L: int, S: int, block_b: int,
 
     Requests (0 = whole axis) snap down to divisors.  Compiled cells take
     whole (8, 128) vregs of scenarios (rows a multiple of 8, or all R)
-    and shrink the state tile, then the scenario and slot tiles, until
-    their blocks fit ``_VMEM_BLOCK_BYTES``; a cell that still does not
-    fit asks for ``_VMEM_LIMIT_BYTES``."""
+    and shrink the state tile down to 2, then the scenario and slot
+    tiles, then the state tile to 1, until their blocks fit
+    ``_VMEM_BLOCK_BYTES``; a cell that still does not fit asks for
+    ``_VMEM_LIMIT_BYTES``.  Each state tile re-reads the cell's ``dp``
+    block, while the slot tile costs no bytes (``tr`` is fetched once per
+    state tile), so the last state split is made last: at L = 52
+    (B = 4096, M = S = 8) 1 slot x 2 states and 2 slots x 1 state take
+    the same VMEM, and a launch ran 481 us against 829 us on one v5e."""
     rb = divisor_leq(R, max(1, block_b // C) if block_b else R,
                      1 if interpret else 8)
     bm = divisor_leq(M, block_m or M)
@@ -175,12 +180,14 @@ def _tiles(M: int, R: int, C: int, L: int, S: int, block_b: int,
     if interpret:
         return rb, bm, bs, None
     while _cell_bytes(bm, bs, rb, C, L, S) > _VMEM_BLOCK_BYTES:
-        if bs > 1:
+        if bs > 2:
             bs = divisor_leq(S, bs - 1)
         elif rb > 8 and divisor_leq(R, rb - 1, 8) < rb:
             rb = divisor_leq(R, rb - 1, 8)
         elif bm > 1:
             bm = divisor_leq(M, bm - 1)
+        elif bs > 1:
+            bs = divisor_leq(S, bs - 1)
         else:
             return rb, bm, bs, _VMEM_LIMIT_BYTES
     return rb, bm, bs, None

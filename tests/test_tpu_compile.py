@@ -64,7 +64,9 @@ def compiled_text(fn, one_chip, *shapes) -> str:
     # U = L = 32 takes one state per cell and the raised VMEM limit
     pytest.param(32, 32, 32, 64, id="32-32-32"),
     # the AlexNet kernel cell's step: 8 slots, L = 11, B = 8192 on lanes
-    pytest.param(8, 11, 8, 8192, id="8-11-8")])
+    pytest.param(8, 11, 8, 8192, id="8-11-8"),
+    # the ResNet-50 cell's step: 8 slots, L = 52, B = 4096 on lanes
+    pytest.param(8, 52, 8, 4096, id="8-52-8")])
 def test_tropical_dp_compiles(one_chip, U, L, M, B):
     S = U
     R, C = lane_split(B)
