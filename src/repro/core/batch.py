@@ -357,7 +357,7 @@ def links_from_assignment_batched(assign: jnp.ndarray, source: jnp.ndarray,
 # batched):
 #
 # * ``_chain_dp_solve``           — lax.scan wavefront over layers with dense
-#                                   [L, B, S+1] parent pointers and a reverse
+#                                   [L, S, B] parent pointers and a reverse
 #                                   lax.scan backtrack, all in ONE jit call.
 #                                   O(1) traced ops per layer, so U, L >= 32
 #                                   compiles in seconds.  This is the default.
@@ -366,6 +366,62 @@ def links_from_assignment_batched(assign: jnp.ndarray, source: jnp.ndarray,
 #                                   Kept verbatim as the benchmark baseline
 #                                   (``benchmarks/bench_placement.py``) and as
 #                                   a second parity oracle in the tests.
+#
+# Both scan solvers (and the kernel path, in its lane layout) walk their
+# parent pointers with ``_chain_dp_backtrack``: row by row, layer j reading
+# table row j as the reverse scan's ``xs`` and picking its state by a
+# compare-and-select over the S states — no gather across the table.
+
+
+def _chain_dp_backtrack(pa: jnp.ndarray, ps: jnp.ndarray,
+                        s_best: jnp.ndarray, order_arr: jnp.ndarray,
+                        state_axis: int) -> jnp.ndarray:
+    """Walk the chain DP's parent pointers back from ``s_best``: the device
+    id of every layer, ``[L, *rows]`` with ``rows = s_best.shape``.
+
+    ``pa`` / ``ps`` stack the parents of table rows b = 1..L (block start,
+    predecessor state) as written by the forward scan: ``pa[b-1]`` holds
+    states 1..S on ``state_axis`` and the rows, laid out as ``s_best``, on
+    the other axes.  Any layout serves; each step is elementwise over rows.
+
+    A reverse scan over layers j carries ``(b, s, a, s0)``: the DP state
+    whose block ``[a, b)`` holds layer j, and that state's parents.  b only
+    changes by a hop to b = j, so the parent row ``b - 1`` is exactly the
+    step's own ``xs`` row j at the first step (b = L) and right after each
+    hop; there (a, s0) are picked at state s (s = 0 has no parents and
+    reads 0), and between hops they stay what they were — the same walk,
+    value for value, as gathering ``pa[b-1, row, s]`` at every step.
+    """
+    L = pa.shape[0]
+    shape = [1] * (s_best.ndim + 1)
+    shape[state_axis] = order_arr.shape[0]
+    states = jnp.arange(1, order_arr.shape[0] + 1).reshape(shape)
+    order_s = order_arr.reshape(shape)
+
+    def pick(p, s):                  # p at state s per row, 0 for no state
+        hit = jnp.expand_dims(s, state_axis) == states
+        return jnp.where(hit, p, 0).sum(state_axis, dtype=jnp.int32)
+
+    def backward(carry, x):
+        b, s, a, s0 = carry
+        j, pa_j, ps_j = x
+        at_end = j == b - 1                # layer j closes the block [a, b)
+        a = jnp.where(at_end, pick(pa_j, s), a)
+        s0 = jnp.where(at_end, pick(ps_j, s), s0)
+        dev = pick(order_s, jnp.maximum(s, 1))
+        at_start = j == a                  # layer j opens the block: hop to
+        nb = jnp.where(at_start, a, b)     # the parent state for layer j-1
+        ns = jnp.where(at_start, s0, s)
+        return (nb, ns, a, s0), dev
+
+    zero = jnp.zeros_like(s_best)
+    init = (jnp.full_like(s_best, L), s_best, zero, zero)
+    # the scope holds the reverse scan alone: opened above ``init`` it
+    # renumbers instructions of the compiled rollout
+    with jax.named_scope("backtrack"):
+        _, devs = jax.lax.scan(backward, init, (jnp.arange(L), pa, ps),
+                               reverse=True)
+    return devs
 
 
 @partial(jax.jit, static_argnames=("order",))
@@ -379,7 +435,7 @@ def _chain_dp_solve_kernelized(compute: jnp.ndarray, memory: jnp.ndarray,
     native source-slot axis.
 
     Same recurrence, values and tie-breaks as ``_chain_dp_solve`` — the
-    masks and the backward scan are that function's code; only the
+    masks are that function's code and the backtrack its helper; only the
     forward-step relaxation is swapped for
     ``kernels.tropical_dp.dp_wavefront_step``.  ``sources`` carries a slot
     axis [B, M] so the multi-source planner shares ONE kernel launch per
@@ -394,8 +450,9 @@ def _chain_dp_solve_kernelized(compute: jnp.ndarray, memory: jnp.ndarray,
     [M, S, Bt, C] are built once per solve; the scan carries the table
     ``dp`` [M, L+1, S+1, Bt, C], hands it to the kernel whole (the kernel
     reads rows 0..L-1) and writes each new row in place, so nothing is
-    copied or transposed per step.  The parent pointers are transposed
-    once, after the scan, for the backtrack.  Returns
+    copied or transposed per step.  The backtrack walks the parent
+    pointers in that layout too (``_chain_dp_backtrack``, state axis 1);
+    only its ``[L, M, Bt, C]`` result is laid back out.  Returns
     ``(assign [B, M, L], latency [B, M])``, bitwise-identical to vmapping
     ``_chain_dp_solve`` over the slot axis.
     """
@@ -458,38 +515,13 @@ def _chain_dp_solve_kernelized(compute: jnp.ndarray, memory: jnp.ndarray,
         return dp, (pa, ps)
 
     dp, (pa, ps) = jax.lax.scan(forward, dp0, jnp.arange(1, L + 1))
-    # backtrack on R = B * M flattened (scenario, slot) rows —
-    # _chain_dp_solve's reverse scan verbatim
-    R = B * M
-    final = jnp.moveaxis(from_lanes(dp[:, L], B), -1, 0)            # [B,M,S+1]
-    final = final.reshape(R, S + 1)
-    s_best = jnp.argmin(final, 1).astype(jnp.int32)
+    final = dp[:, L]                                          # [M,S+1,Bt,C]
+    s_best = jnp.argmin(final, 1).astype(jnp.int32)           # [M, Bt, C]
     latency = final.min(1)
-
-    def parent_rows(p):              # [L, M, S, Bt, C] -> [L, R, S+1]
-        p = jnp.moveaxis(from_lanes(p, B), -1, 1).reshape(L, R, S)
-        return jnp.concatenate([jnp.zeros((L, R, 1), jnp.int32), p], -1)
-
-    pa, ps = parent_rows(pa), parent_rows(ps)
-    rows = jnp.arange(R)
-
-    def backward(carry, j):
-        b, s = carry
-        dev = order_arr[jnp.maximum(s - 1, 0)]                      # [R]
-        bi = jnp.clip(b - 1, 0, L - 1)
-        a = pa[bi, rows, s]
-        s0 = ps[bi, rows, s]
-        at_start = j == a
-        nb = jnp.where(at_start, a, b)
-        ns = jnp.where(at_start, s0, s)
-        return (nb, ns), dev
-
-    init = (jnp.full((R,), L, jnp.int32), s_best)
-    with jax.named_scope("backtrack"):
-        _, devs = jax.lax.scan(backward, init, jnp.arange(L - 1, -1, -1))
-    assign = devs[::-1].T.astype(jnp.int32)                         # [R, L]
-    assign = jnp.where(jnp.isfinite(latency)[:, None], assign, -1)
-    return assign.reshape(B, M, L), latency.reshape(B, M)
+    devs = _chain_dp_backtrack(pa, ps, s_best, order_arr, 1)  # [L,M,Bt,C]
+    assign = jnp.where(jnp.isfinite(latency), devs, -1)
+    return (from_lanes(assign, B).transpose(2, 1, 0),               # [B, M, L]
+            from_lanes(latency, B).T)                               # [B, M]
 
 
 @partial(jax.jit, static_argnames=("order", "use_kernel"))
@@ -509,9 +541,10 @@ def _chain_dp_solve(compute: jnp.ndarray, memory: jnp.ndarray,
     scalar solver's loop order (a outer, s0 inner, strict improvement) via
     first-argmin over the flattened (a, s0) axis.
 
-    Backward pass: a reverse ``lax.scan`` over layers walks the parent
-    pointers (pa = block start, ps = predecessor state, gathered per batch
-    element) and emits the full [B, L] device-id assignment — no host loop.
+    Backward pass: ``_chain_dp_backtrack``, a reverse ``lax.scan`` over
+    layers, walks the parent pointers (pa = block start, ps = predecessor
+    state, stacked [L, S, B]) row by row and emits the full [B, L]
+    device-id assignment — no host loop.
 
     ``use_kernel=True`` routes the forward relaxation through the Pallas
     tropical-DP kernel (``_chain_dp_solve_kernelized`` with a single source
@@ -585,41 +618,15 @@ def _chain_dp_solve(compute: jnp.ndarray, memory: jnp.ndarray,
         a_best = jnp.argmin(cand, 1).astype(jnp.int32)              # [B, S]
         row = jnp.concatenate([jnp.full((B, 1), INF), cand.min(1)], 1)
         dp = dp.at[:, b, :].set(row)
-        pad = jnp.zeros((B, 1), jnp.int32)
-        pa = jnp.concatenate([pad, a_best], 1)                      # [B, S+1]
-        ps = jnp.concatenate(
-            [pad, jnp.take_along_axis(s0_best, a_best[:, None, :], 1)[:, 0]],
-            1)
-        return dp, (pa, ps)
+        ps = jnp.take_along_axis(s0_best, a_best[:, None, :], 1)[:, 0]
+        return dp, (a_best.T, ps.T)                                 # [S, B]
 
     dp, (pa, ps) = jax.lax.scan(forward, dp0, jnp.arange(1, L + 1))
     final = dp[:, L, :]                                             # [B, S+1]
     s_best = jnp.argmin(final, 1).astype(jnp.int32)
     latency = final.min(1)
-
-    # Reverse scan j = L-1 .. 0; carry (b, s) = the DP state whose block
-    # [a, b) contains layer j.  pa/ps are stacked per forward step, so the
-    # parents of table row b live at pa[b-1].
-    rows = jnp.arange(B)
-
-    def backward(carry, j):
-        b, s = carry
-        dev = order_arr[jnp.maximum(s - 1, 0)]                      # [B]
-        bi = jnp.clip(b - 1, 0, L - 1)
-        a = pa[bi, rows, s]
-        s0 = ps[bi, rows, s]
-        at_start = j == a                  # layer j opens the block: hop to
-        nb = jnp.where(at_start, a, b)     # the parent state for layer j-1
-        ns = jnp.where(at_start, s0, s)
-        return (nb, ns), dev
-
-    init = (jnp.full((B,), L, jnp.int32), s_best)
-    # the scope holds the reverse scan alone: opened above ``init`` it
-    # renumbers instructions of the compiled rollout
-    with jax.named_scope("backtrack"):
-        _, devs = jax.lax.scan(backward, init, jnp.arange(L - 1, -1, -1))
-    assign = devs[::-1].T.astype(jnp.int32)                         # [B, L]
-    assign = jnp.where(jnp.isfinite(latency)[:, None], assign, -1)
+    devs = _chain_dp_backtrack(pa, ps, s_best, order_arr, 0)        # [L, B]
+    assign = jnp.where(jnp.isfinite(latency)[:, None], devs.T, -1)  # [B, L]
     return assign, latency
 
 

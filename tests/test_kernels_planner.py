@@ -21,6 +21,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import repro.core.batch as batch
 import repro.kernels as kernels
 from repro.core.batch import solve_chain_dp_batched, solve_chain_dp_multisource
 from repro.core.channel import RadioParams
@@ -418,6 +419,146 @@ class TestSolverKernelPath:
             p["mem_cap"], p["compute_cap"], p["throughput"], p["rate"],
             p["source"], active, use_kernel=True)
         assert (a1 != 1).all()
+
+
+# ---------------------------------------------------------------------------
+# backtrack: the row-by-row walk against the gather walk
+# ---------------------------------------------------------------------------
+
+
+def gather_walk(pa, ps, s_best, order_arr, state_axis):
+    """The chain DP's backtrack as a gather: ``_chain_dp_backtrack``'s
+    signature and result, computed the way the solvers once did it.  The
+    tables are laid out as ``[L, R, S+1]`` rows with a zero column for
+    state 0, and every step gathers ``pa[b-1, row, s]``."""
+    L, S = pa.shape[0], order_arr.shape[0]
+
+    def rows(p):                     # [L, ..., S, ...] -> [L, R, S+1]
+        p = jnp.moveaxis(p, state_axis + 1, -1).reshape(L, -1, S)
+        return jnp.concatenate([jnp.zeros_like(p[..., :1]), p], -1)
+
+    pa, ps = rows(pa), rows(ps)
+    R = pa.shape[1]
+    ix = jnp.arange(R)
+
+    def backward(carry, j):
+        b, s = carry
+        dev = order_arr[jnp.maximum(s - 1, 0)]
+        bi = jnp.clip(b - 1, 0, L - 1)
+        a = pa[bi, ix, s]
+        s0 = ps[bi, ix, s]
+        at_start = j == a
+        return (jnp.where(at_start, a, b), jnp.where(at_start, s0, s)), dev
+
+    init = (jnp.full((R,), L, jnp.int32), s_best.reshape(R))
+    _, devs = jax.lax.scan(backward, init, jnp.arange(L - 1, -1, -1))
+    return devs[::-1].reshape((L,) + s_best.shape)
+
+
+def parent_tables(seed, L, S, n):
+    """Random valid parent tables ``[L, S, n]`` (``pa[b-1] <= b-1``, ``ps``
+    in 0..S) and ``s_best`` [n], the rows cycling through four kinds: any
+    pointers; ``s_best = 0``; all-infeasible (zero pointers and
+    ``s_best = 0``, as an all-inf DP writes them); and ties (every state
+    of a row carries the same parents, as on a symmetric swarm)."""
+    rng = np.random.default_rng(seed)
+    bound = np.arange(1, L + 1)[:, None, None]
+    pa = (rng.random((L, S, n)) * bound).astype(np.int32)
+    ps = rng.integers(0, S + 1, (L, S, n)).astype(np.int32)
+    s_best = rng.integers(0, S + 1, n).astype(np.int32)
+    kind = np.arange(n) % 4
+    s_best[kind == 1] = 0
+    pa[:, :, kind == 2] = 0
+    ps[:, :, kind == 2] = 0
+    s_best[kind == 2] = 0
+    pa[:, :, kind == 3] = pa[:, :1, kind == 3]
+    ps[:, :, kind == 3] = ps[:, :1, kind == 3]
+    order = rng.permutation(S).astype(np.int32)
+    return pa, ps, s_best, order
+
+
+def laid_out(layout, pa, ps, s_best, M=3):
+    """The tables in a solver's layout, with the helper's ``state_axis``:
+    ``rows`` is the jnp path's [L, S, B]; ``lanes`` the kernel path's
+    [L, M, S, Bt, C] (``to_lanes``, B ragged so lanes are padded)."""
+    if layout == "rows":
+        return jnp.asarray(pa), jnp.asarray(ps), jnp.asarray(s_best), 0
+    L, S, n = pa.shape
+    lane = (lambda p: to_lanes(jnp.asarray(
+        p.reshape(L, S, M, n // M).transpose(0, 2, 1, 3)), 0))
+    return (lane(pa), lane(ps),
+            to_lanes(jnp.asarray(s_best.reshape(M, n // M)), 0), 1)
+
+
+class TestBacktrack:
+    WALK = staticmethod(jax.jit(batch._chain_dp_backtrack,
+                                static_argnames=("state_axis",)))
+    GATHER = staticmethod(jax.jit(gather_walk,
+                                  static_argnames=("state_axis",)))
+
+    @pytest.mark.parametrize("L", [1, 2, 7, 11, 52])
+    @pytest.mark.parametrize("layout", ["rows", "lanes"])
+    def test_walk_matches_gather_walk(self, layout, L):
+        """Bit for bit against the gather walk on random valid tables, in
+        both solvers' layouts; 600 rows = 3 slots x a ragged B = 200."""
+        pa, ps, s_best, order = parent_tables(L, L, 8, 600)
+        pa, ps, s_best, axis = laid_out(layout, pa, ps, s_best)
+        order = jnp.asarray(order)
+        got = self.WALK(pa, ps, s_best, order, state_axis=axis)
+        want = self.GATHER(pa, ps, s_best, order, state_axis=axis)
+        assert got.shape == (L,) + s_best.shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("use_kernel", [False, True])
+    @pytest.mark.parametrize("symmetric", [False, True])
+    def test_solvers_walk_as_the_gather_walk(self, monkeypatch, use_kernel,
+                                             symmetric):
+        """On the tables the solvers' own forward scans write — exact ties
+        (symmetric swarm) and scenarios with no live UAV (all-infeasible)
+        — each solver plans the same with the gather walk swapped in."""
+        p = dp_problem(12 + symmetric, B=6, L=7, symmetric=symmetric)
+        p["active"] = p["active"].copy()
+        p["active"][:2] = False
+        args, order = batch._as_dp_args(
+            p["compute"], p["memory"], p["act_bits"], p["input_bits"],
+            p["mem_cap"], p["compute_cap"], p["throughput"], p["rate"],
+            p["sources"] if use_kernel else p["source"], p["active"], None)
+        fn = (batch._chain_dp_solve_kernelized if use_kernel
+              else batch._chain_dp_solve).__wrapped__
+
+        def solve():                 # a fresh trace picks up the patch
+            return jax.jit(lambda *a: fn(*a, order=order))(*args)
+
+        a0, l0 = solve()
+        monkeypatch.setattr(batch, "_chain_dp_backtrack", gather_walk)
+        a1, l1 = solve()
+        assert (np.asarray(a0)[:2] == -1).all()
+        np.testing.assert_array_equal(np.asarray(a0), np.asarray(a1))
+        np.testing.assert_array_equal(np.asarray(l0), np.asarray(l1))
+
+    @pytest.mark.parametrize("layout", ["rows", "lanes"])
+    def test_walk_lowers_without_gather(self, layout):
+        """The walk reads its rows as scan slices and picks states by
+        select: its lowering holds no gather (the gather walk's does)."""
+        pa, ps, s_best, order = parent_tables(0, 11, 8, 600)
+        *tables, axis = laid_out(layout, pa, ps, s_best)
+        args = (*tables, jnp.asarray(order))
+        text = self.WALK.lower(*args, state_axis=axis).as_text()
+        assert "gather" not in text
+        assert "gather" in self.GATHER.lower(*args, state_axis=axis).as_text()
+
+    def test_kernel_solve_holds_no_row_tables(self):
+        """The kernel path walks its parents in the lane layout: no int32
+        ``[L, B*M, S+1]`` table is built (L = 7, B = 200, M = 3, S = 5)."""
+        p = dp_problem(3, B=200, L=7)
+        args, order = batch._as_dp_args(
+            p["compute"], p["memory"], p["act_bits"], p["input_bits"],
+            p["mem_cap"], p["compute_cap"], p["throughput"], p["rate"],
+            p["sources"], p["active"], None)
+        text = batch._chain_dp_solve_kernelized.lower(
+            *args, order=order).as_text()
+        assert "tensor<7x600x6xi32>" not in text
+        assert "tensor<7x3x2x128xi32>" in text          # [L, M, Bt, C]
 
 
 # ---------------------------------------------------------------------------
